@@ -59,9 +59,9 @@ def run_smoke(out_dir: str, backend: str | None = None) -> None:
     worker's hits/misses exactly); the second (serial) pass must be served
     entirely from the cache and produce byte-identical profiles.  A third,
     uncached serial pass re-traces the sweep on the *other* reduction
-    backend (jax when this run used numpy and vice versa, skipped when
-    only one backend is importable) and must also be byte-identical —
-    the cross-backend exactness contract from ``repro.core.backend``,
+    backend (jax when this run used numpy and vice versa) and must also
+    be byte-identical — the cross-backend exactness contract from
+    ``repro.core.backend``,
     asserted end to end.  Then the lazily-materialized trace store's
     regime is exercised: every ``SCALE_EXPERIMENTS`` app (the paper's
     three plus the beatnik global-communication stressor) sweeps its
@@ -118,15 +118,12 @@ def run_smoke(out_dir: str, backend: str | None = None) -> None:
 
     # cross-backend pass: re-trace (no cache) on the other backend and
     # require byte-identical profiles
-    used = type(resolve_backend(backend)).__name__
-    other = "jax" if used == "NumpyBackend" else "numpy"
-    if type(resolve_backend(other)).__name__ == used:
-        other = None  # jax not importable: only one backend available
+    used = resolve_backend(backend).name
+    other = "jax" if used == "numpy" else "numpy"
     t_x0 = time.perf_counter()
-    if other is not None:
-        cross = run_experiment(spec, cache=None, executor="serial", backend=other)
-        for a, b in zip(first, cross):
-            assert a.to_json() == b.to_json(), (used, other)
+    cross = run_experiment(spec, cache=None, executor="serial", backend=other)
+    for a, b in zip(first, cross):
+        assert a.to_json() == b.to_json(), (used, other)
     t_x1 = time.perf_counter()
 
     # one aggregated Thicket frame over the sweep's profile JSONs
@@ -550,6 +547,9 @@ def main() -> None:
         "(default: REPRO_BACKEND env, else numpy)",
     )
     args = parser.parse_args()
+    from repro.core.devices import use_compile_cache
+
+    use_compile_cache()
     if args.chaos:
         run_chaos(args.out, backend=args.backend)
     elif args.live:

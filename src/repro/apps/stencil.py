@@ -9,10 +9,8 @@ communication regions were designed to bracket.
 
 Everything here runs *inside* ``shard_map`` and uses the instrumented
 collectives so profiling sees it.  All mesh / shard_map construction is
-routed through :mod:`repro.core.compat`, the version-portability substrate
-(jax 0.4.x and >= 0.5 expose these APIs under different names and
-signatures — see compat's module docstring for the exact contract), so
-this module works unchanged on every supported JAX.
+routed through :mod:`repro.core.compat`, the one module that touches
+version-sensitive JAX APIs (see its module docstring for the contract).
 """
 
 from __future__ import annotations

@@ -99,7 +99,8 @@ from typing import Optional
 
 from repro.benchpark.aggregator import publish_shard
 from repro.benchpark.spec import ExperimentSpec
-from repro.core.backend import use_backend
+from repro.core.backend import resolve_backend, use_backend
+from repro.core.devices import MODELED_DEVICE_KIND, chip_peaks
 from repro.core.faultinject import (
     InjectedFault,
     active_plan,
@@ -111,10 +112,8 @@ from repro.core.faultinject import (
 from repro.core.profiler import CommPatternProfiler, CommProfile, trace_observer
 from repro.core.thicket import Frame
 
-# same system model the dry-run uses (TPU v5e)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+#: Peaks of the modeled system (TPU v5e) behind the modeled step seconds.
+_PEAKS = chip_peaks(MODELED_DEVICE_KIND)
 
 #: Environment knobs for the shared profile cache.
 CACHE_DIR_ENV = "REPRO_PROFILE_CACHE_DIR"
@@ -161,6 +160,20 @@ def _pool_mp_context():
         return multiprocessing.get_context("spawn")
 
 
+def _trace_only_worker() -> None:
+    """Process-pool initializer: keep the worker on the host CPU.
+
+    A chip belongs to one process, and the parent that runs the sweep
+    holds it; a worker that reached for it would fail or hang.  Workers
+    only trace (``eval_shape`` on abstract meshes), so they pin JAX to the
+    CPU before anything in them initializes a backend.
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def default_cache_dir() -> str:
     """The profile-cache directory shared by the runner and the
     ``benchmarks/`` figure scripts (override via ``REPRO_PROFILE_CACHE_DIR``)."""
@@ -189,6 +202,7 @@ def _flops_estimate(app: str, cfg) -> float:
 
 
 def _roofline_seconds(app: str, cfg, profile: CommProfile) -> float:
+    """Modeled (never measured) step seconds on the modeled chip."""
     flops = _flops_estimate(app, cfg)
     mem = flops * 2.0  # ~2 bytes/flop for stencil codes (bandwidth-bound)
     wire = (
@@ -196,7 +210,11 @@ def _roofline_seconds(app: str, cfg, profile: CommProfile) -> float:
         if profile.regions
         else 0
     )
-    return max(flops / PEAK_FLOPS, mem / HBM_BW, wire / LINK_BW)
+    return max(
+        flops / _PEAKS.flops_bf16,
+        mem / _PEAKS.hbm_bytes_per_s,
+        wire / _PEAKS.ici_link_bytes_per_s,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1100,8 +1118,13 @@ def run_experiment(
     aggregated Thicket-frame CSV (one row per profile x region).
     ``backend``: reduction-backend name for every traced point (see
     ``repro.core.backend``; default resolves from ``REPRO_BACKEND``) — all
-    backends produce byte-identical profiles.  ``live_dir`` enables live
-    mode: each point is profiled incrementally and its mergeable summary
+    backends produce byte-identical profiles.  One process per chip:
+    ``"process"`` workers run with ``JAX_PLATFORMS=cpu`` and only trace and
+    reduce on the host, so a device reduction (the jax backend on an
+    accelerator) under ``"process"`` raises ``ValueError`` instead of
+    running on the workers' CPU under the device's name; ``"serial"`` and
+    ``"thread"`` reduce in this process, on its device.  ``live_dir``
+    enables live mode: each point is profiled incrementally and its mergeable summary
     deltas (``live_shards`` per traced point) are published to that
     directory for a concurrent
     :class:`~repro.benchpark.aggregator.SweepAggregator`; returned
@@ -1199,10 +1222,19 @@ def run_experiment(
     concurrent = executor != "serial" and max_workers > 1 and len(todo) > 1
 
     if concurrent and executor == "process":
+        be = resolve_backend(backend)
+        if be.name == "jax" and be.platform != "cpu":
+            raise ValueError(
+                f"executor='process' traces on CPU-only workers; the jax "
+                f"reduction on {be.platform!r} must run in the process that "
+                f"holds the device (use executor='serial' or 'thread')"
+            )
 
         def make_executor():
             return ProcessPoolExecutor(
-                max_workers=max_workers, mp_context=_pool_mp_context()
+                max_workers=max_workers,
+                mp_context=_pool_mp_context(),
+                initializer=_trace_only_worker,
             )
 
         def submit_one(ex, i, attempt):

@@ -27,19 +27,18 @@ Two interchangeable implementations with **bit-identical** outputs:
     :func:`_dedup_strategy`.
 
 ``JaxBackend``
-    ``jax.jit``-compiled reductions with x64 enabled *inside the backend
-    only* (``jax.experimental.enable_x64`` scopes every call, so the
-    process-global default dtype is untouched).  Exact int64 matmuls run on
-    device as f64 ``dot_general``: a single f64 product is exact whenever
-    ``max|w| * max|slab| * S < 2**53``, and larger values split into
-    limb-decomposed partial matmuls recombined by shifts (still exact —
-    every partial product and partial sum is an integer below 2**53).  An
-    optional **Pallas segmented-reduce kernel** (the house
-    ``kernels/ssd_scan.py`` idiom: sequential grid over fixed-size row
-    blocks, VMEM scratch accumulator initialized at step 0 and emitted at
-    the last step) backs ``block_reduce`` / ``segment_reduce``; it
-    auto-enables on TPU and runs in ``interpret=True`` mode elsewhere so
-    parity is testable on CPU.
+    Reductions on the default jax device.  Exact int64 matmuls run as one
+    int8 x int8 -> int32 device dot over signed 7-bit limbs of both
+    operands (every partial sum is an exact int32), recombined on the host
+    modulo 2**64.  ``block_reduce`` / ``segment_reduce`` run a **Pallas
+    segmented-reduce kernel** on TPU: 32-bit tiles on a (limb, column,
+    row) grid, sums as one-hot bf16 matmuls over 8-bit limbs on the MXU,
+    max/min as masked column reductions (see
+    :func:`_pallas_segment_reduce` for how exactness is kept).  Off TPU
+    they run XLA's ``segment_*`` ops, or the kernel in interpret mode when
+    a CPU test asks for it.  x64 is scoped to the calls that move int64
+    through XLA (``compat.enable_x64``), never enabled process-wide, and
+    never reaches the kernel.
 
 Boundary contract (what the profilers rely on):
 
@@ -56,9 +55,9 @@ Boundary contract (what the profilers rely on):
 Selection: :func:`resolve_backend` resolves, in priority order, an explicit
 ``backend=`` argument (name or instance), a :func:`use_backend` thread-local
 override, the ``REPRO_BACKEND`` environment variable, and finally
-``"numpy"``.  Asking for jax when it is missing or x64 cannot be enabled
-warns and falls back to NumPy instead of crashing; an unknown *explicit*
-name raises ``ValueError`` while an unknown environment value only warns.
+``"numpy"``.  Nothing falls back: asking for jax when it is missing or x64
+cannot be enabled raises :class:`BackendUnavailable`, and an unknown name
+raises ``ValueError``.
 """
 
 from __future__ import annotations
@@ -66,7 +65,6 @@ from __future__ import annotations
 import functools
 import os
 import threading
-import warnings
 from contextlib import contextmanager
 from typing import Optional, Union
 
@@ -74,9 +72,6 @@ import numpy as np
 
 #: Environment variable naming the default reduction backend.
 BACKEND_ENV = "REPRO_BACKEND"
-
-#: f64 integer-exactness bound: every integer with |v| < 2**53 is exact.
-_F64_EXACT = 1 << 53
 
 #: Dense dedup bitmaps never allocate more than this many boolean cells at
 #: once; past it the scatter chunks over region groups (or falls back to the
@@ -452,7 +447,7 @@ class NumpyBackend(ReduceBackend):
 
 
 # ---------------------------------------------------------------------------
-# jax backend: exact f64/limb matmuls + optional Pallas segmented reduce
+# jax backend: exact int8-limb matmuls + the Pallas segmented reduce
 # ---------------------------------------------------------------------------
 
 
@@ -461,94 +456,253 @@ class BackendUnavailable(RuntimeError):
 
 
 def _import_jax():
-    """Deferred jax import (monkeypatched by the fallback tests)."""
+    """Deferred jax import (monkeypatched by the unavailability tests)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+
+    from repro.core.compat import enable_x64
 
     return jax, jnp, enable_x64
 
 
 def _x64_ok() -> bool:
-    """True when ``enable_x64`` actually yields 64-bit array types."""
-    try:
-        jax, jnp, enable_x64 = _import_jax()
-        with enable_x64():
-            return bool(jnp.zeros((), jnp.int64).dtype == np.dtype(np.int64))
-    except Exception:
-        return False
+    """True when the x64 scope actually yields 64-bit array types."""
+    _, jnp, enable_x64 = _import_jax()
+    with enable_x64():
+        return bool(jnp.zeros((), jnp.int64).dtype == np.dtype(np.int64))
 
 
-def _nlimbs(vmax: int, t: int) -> int:
-    return max(1, -(-max(vmax, 1).bit_length() // t))
+#: Matmul limbs are 7 bits wide (the top limb carries the sign), so every
+#: limb is an int8 and one int8 x int8 product is at most 2**14 in size.
+_LIMB_BITS = 7
+
+#: int32 accumulation of int8 products stays exact over this many terms
+#: (2**17 - 1 products of magnitude <= 2**14 sum below 2**31); longer
+#: contractions split into chunks summed on the host.
+_LIMB_DOT_MAX_K = (1 << 17) - 1
 
 
-def _limb_width(other_max: int, s: int) -> int:
-    """Widest limb t with (2**t - 1) * other_max * s < 2**53."""
-    om, sm = max(other_max, 1), max(s, 1)
-    t = 0
-    while t < 63 and ((1 << (t + 1)) - 1) * om * sm < _F64_EXACT:
-        t += 1
-    return t
+def _n_limbs(arr: np.ndarray) -> int:
+    """Fewest signed 7-bit limbs that hold every value: the top limb
+    ``v >> 7 * (k - 1)`` must lie in the int8 range."""
+    lo, hi = int(arr.min()), int(arr.max())
+    k = 1
+    while not (-(1 << (_LIMB_BITS * k)) <= lo and hi < (1 << (_LIMB_BITS * k))):
+        k += 1
+    return k
 
 
-def _limb_plan(amax: int, bmax: int, s: int) -> Optional[tuple]:
-    """(ta, ka, tb, kb) limb widths/counts making every partial f64 dot
-    exact, or None when even 1-bit limbs overflow (true int64 results
-    cannot reach that regime; callers fall back to the NumPy matmul)."""
-    if amax * bmax * max(s, 1) < _F64_EXACT:
-        return (64, 1, 64, 1)
-    ta = _limb_width(bmax, s)
-    if ta >= 1:
-        return (ta, _nlimbs(amax, ta), 64, 1)
-    tb = 0  # split both sides: grow symmetric widths while exact
-    while ((1 << (tb + 1)) - 1) ** 2 * max(s, 1) < _F64_EXACT:
-        tb += 1
-    if tb < 1:
-        return None
-    ta = _limb_width((1 << tb) - 1, s)
-    if ta < 1:
-        return None
-    return (ta, _nlimbs(amax, ta), tb, _nlimbs(bmax, tb))
-
-
-def _limbs(arr: np.ndarray, t: int, k: int) -> np.ndarray:
-    """Stack ``k`` little-endian limbs of width ``t`` bits: (k, *arr.shape)."""
-    if k == 1 and t >= 64:
-        return arr[None]
-    mask = np.int64((1 << t) - 1)
-    return np.stack([(arr >> (t * i)) & mask for i in range(k)])
+def _limbs(arr: np.ndarray, k: int) -> np.ndarray:
+    """``(k, *arr.shape)`` int8 limbs with ``arr == sum(limb_i << 7 i)``:
+    limbs below the top are in ``[0, 127]``, the top one is signed."""
+    out = [((arr >> (_LIMB_BITS * i)) & 127) for i in range(k - 1)]
+    out.append(arr >> (_LIMB_BITS * (k - 1)))
+    return np.stack(out).astype(np.int8)
 
 
 @functools.lru_cache(maxsize=None)
-def _limb_dot_fn(ka: int, kb: int, ta: int, tb: int):
-    """jit-compiled exact dot over limb stacks (cached per limb plan)."""
+def _limb_dot_fn():
+    """jit-compiled int8 x int8 -> int32 dot (exact on every backend)."""
     jax, jnp, _ = _import_jax()
+    return jax.jit(
+        lambda a, b: jax.lax.dot(a, b, preferred_element_type=jnp.int32)
+    )
 
-    def dot(a_limbs, b_limbs):  # (ka, G, S) i64, (kb, S, R) i64 -> (G, R) i64
-        af = a_limbs.astype(jnp.float64)
-        bf = b_limbs.astype(jnp.float64)
-        out = None
+
+def _limb_matmul(w: np.ndarray, grid: np.ndarray) -> np.ndarray:
+    """Exact int64 ``w @ grid`` as one device dot over stacked int8 limbs.
+
+    ``(ka*G, S) @ (S, kb*R)`` gives every limb-pair product in int32; the
+    host recombines them with shifts in uint64, i.e. modulo 2**64 — the
+    same wrap-around NumPy's int64 matmul has, so results are identical
+    for every int64 input, negative values included.
+    """
+    g, s = w.shape
+    r = grid.shape[1]
+    ka, kb = _n_limbs(w), _n_limbs(grid)
+    a = _limbs(w, ka).reshape(ka * g, s)
+    b = np.moveaxis(_limbs(grid, kb), 0, 1).reshape(s, kb * r)
+    acc = np.zeros((g, r), np.uint64)
+    dot = _limb_dot_fn()
+    for lo in range(0, s, _LIMB_DOT_MAX_K):
+        hi = min(s, lo + _LIMB_DOT_MAX_K)
+        part = np.asarray(dot(a[:, lo:hi], b[lo:hi])).astype(np.int64)
+        part = part.reshape(ka, g, kb, r).astype(np.uint64)
         for i in range(ka):
             for j in range(kb):
-                p = jnp.rint(af[i] @ bf[j]).astype(jnp.int64)
-                shift = ta * i + tb * j
-                if shift:
-                    p = p << shift
-                out = p if out is None else out + p
-        return out
-
-    return jax.jit(dot)
+                acc += part[i, :, j] << np.uint64(_LIMB_BITS * (i + j))
+    return acc.view(np.int64)
 
 
 _SEG_OPS = {np.add: "sum", np.maximum: "max", np.minimum: "min"}
 
+#: Tiles of the Pallas segmented reduce.  Rows are the one-hot contraction
+#: axis and ride the 128-lane axis of the segment-id block; segment and
+#: column tiles follow the (8, 128) int32 tiling.  The column (rank) axis
+#: is a grid axis, so VMEM use is fixed whatever the rank count.
+_SEG_ROWS = 256
+_SEG_BLOCK = 128
+_SEG_COLS = 512
 
-def _op_init(op: str, dtype) -> np.generic:
+#: The sum path splits values into 8-bit limbs: exact in bf16, and a
+#: one-hot bf16 matmul over one row tile sums them exactly in f32
+#: (255 * 256 < 2**24).  The int32 accumulator then holds up to this many
+#: rows per segment exactly.
+_SUM_LIMB_BITS = 8
+_SUM_MAX_ROWS = ((1 << 31) - 1) // 255
+
+
+def _seg_layout(seg: np.ndarray, n_segments: int) -> dict:
+    """Row layout that gives every row tile exactly one segment block.
+
+    Rows are sorted by segment, so each block of ``_SEG_BLOCK`` segments
+    owns a contiguous row range; padding each range to whole row tiles
+    (at least one) lets the kernel map row tile -> output segment block
+    through a scalar-prefetched table.  Padding rows carry segment -1 and
+    match nothing.
+    """
+    n = len(seg)
+    n_sb = -(-n_segments // _SEG_BLOCK)
+    bounds = np.searchsorted(seg, np.arange(n_sb + 1) * _SEG_BLOCK)
+    n_rb = np.maximum(1, -(-np.diff(bounds) // _SEG_ROWS))
+    rb_off = np.concatenate(([0], np.cumsum(n_rb)))
+    sb_of_row = seg // _SEG_BLOCK
+    dest = rb_off[sb_of_row] * _SEG_ROWS + np.arange(n) - bounds[sb_of_row]
+    total = int(rb_off[-1])
+    sids = np.full(total * _SEG_ROWS, -1, np.int32)
+    sids[dest] = seg
+    sb_of_rb = np.repeat(np.arange(n_sb), n_rb)
+    first = np.zeros(total, np.int32)
+    first[rb_off[:-1]] = 1
+    # local segment range of each row tile (max/min loop bounds)
+    tiles = sids.reshape(total, _SEG_ROWS)
+    valid = tiles >= 0
+    base = (sb_of_rb * _SEG_BLOCK)[:, None]
+    lo = np.where(valid, tiles - base, _SEG_BLOCK).min(axis=1)
+    hi = np.where(valid, tiles - base, -1).max(axis=1)
+    return dict(
+        dest=dest,
+        n_rows=total * _SEG_ROWS,
+        n_sb=n_sb,
+        sids=sids,
+        sb_of_rb=sb_of_rb.astype(np.int32),
+        first=first,
+        lo=lo.astype(np.int32),
+        hi=hi.astype(np.int32),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _seg_kernel(op: str, k: int, n_rows: int, n_sb: int, c_pad: int, interpret: bool):
+    """jit-compiled segmented reduce over int32 tiles (cached per shape).
+
+    ``sum``: grid (limb, column tile, row tile); each row tile adds the
+    one-hot ``(segments x rows) @ (rows x columns)`` product of its 8-bit
+    limbs into its segment block's int32 output tile on the MXU.
+    ``max``/``min``: grid (column tile, row tile); each row tile folds a
+    masked column reduction per segment it holds into one output row.
+    Row tiles run in order, and a segment block's tiles are consecutive,
+    so its output tile stays resident until the block is done.
+    """
+    jax, jnp, _ = _import_jax()
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, S = _SEG_ROWS, _SEG_BLOCK
+    C = min(_SEG_COLS, c_pad)
+    n_rb, n_cb = n_rows // R, c_pad // C
+
     if op == "sum":
-        return np.zeros((), dtype)[()]
-    info = np.iinfo(dtype) if np.issubdtype(dtype, np.integer) else np.finfo(dtype)
-    return np.asarray(info.min if op == "max" else info.max, dtype)[()]
+
+        def kernel(sb_ref, first_ref, sid_ref, val_ref, out_ref):
+            rb = pl.program_id(2)
+
+            @pl.when(first_ref[rb] == 1)
+            def _init():
+                out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
+
+            seg = sb_ref[rb] * S + jax.lax.broadcasted_iota(jnp.int32, (S, R), 0)
+            onehot = (sid_ref[...] == seg).astype(jnp.bfloat16)
+            part = jnp.dot(
+                onehot,
+                val_ref[...].astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32,
+            )
+            out_ref[...] += part.astype(jnp.int32)
+
+        call = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(k, n_cb, n_rb),
+                in_specs=[
+                    pl.BlockSpec((1, R), lambda j, c, r, sb, fi: (0, r)),
+                    pl.BlockSpec((None, R, C), lambda j, c, r, sb, fi: (j, r, c)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, S, C), lambda j, c, r, sb, fi: (j, sb[r], c)
+                ),
+            ),
+            out_shape=jax.ShapeDtypeStruct((k, n_sb * S, c_pad), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")
+            ),
+            interpret=interpret,
+        )
+
+        def run(sb_of_rb, first, lo, hi, sids, vals):
+            return call(sb_of_rb, first, sids.reshape(1, n_rows), vals)
+
+    else:
+        info = np.iinfo(np.int32)
+        init = int(info.min if op == "max" else info.max)
+        fold = jnp.maximum if op == "max" else jnp.minimum
+        red = jnp.max if op == "max" else jnp.min
+
+        def kernel(sb_ref, first_ref, lo_ref, hi_ref, sid_ref, val_ref, out_ref):
+            rb = pl.program_id(1)
+
+            @pl.when(first_ref[rb] == 1)
+            def _init():
+                out_ref[...] = jnp.full(out_ref.shape, init, jnp.int32)
+
+            base = sb_ref[rb] * S
+            sids = sid_ref[...]
+            vals = val_ref[...]
+
+            def body(s, carry):
+                hit = sids == base + s
+                row = red(jnp.where(hit, vals, init), axis=0, keepdims=True)
+                out_ref[pl.ds(s, 1), :] = fold(out_ref[pl.ds(s, 1), :], row)
+                return carry
+
+            jax.lax.fori_loop(lo_ref[rb], hi_ref[rb] + 1, body, 0)
+
+        idx = lambda c, r, sb, fi, lo, hi: (r, 0)  # noqa: E731
+        call = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(n_cb, n_rb),
+                in_specs=[
+                    pl.BlockSpec((R, 1), idx),
+                    pl.BlockSpec((R, C), lambda c, r, sb, fi, lo, hi: (r, c)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (S, C), lambda c, r, sb, fi, lo, hi: (sb[r], c)
+                ),
+            ),
+            out_shape=jax.ShapeDtypeStruct((n_sb * S, c_pad), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
+            ),
+            interpret=interpret,
+        )
+
+        def run(sb_of_rb, first, lo, hi, sids, vals):
+            return call(sb_of_rb, first, lo, hi, sids.reshape(n_rows, 1), vals)
+
+    return jax.jit(run)
 
 
 def _pallas_segment_reduce(
@@ -558,104 +712,95 @@ def _pallas_segment_reduce(
     op: str,
     *,
     interpret: bool,
-    block: int = 256,
-) -> np.ndarray:
-    """Segmented reduce as a Pallas kernel (ssd_scan idiom).
+) -> Optional[np.ndarray]:
+    """Exact segmented reduce of int64 ``vals (N, C)`` on the Pallas kernel.
 
-    Sequential grid over fixed-size row blocks of the segment-sorted
-    ``vals (N, C)``; the (n_segments, C) accumulator lives in VMEM scratch,
-    initialized at grid step 0 and emitted at the last step.  Rows combine
-    into their segment with a one-hot mask, so dynamic span lengths never
-    reach the kernel.  ``interpret=True`` runs it on CPU for parity tests.
+    Only 32-bit values reach the kernel; x64 stays on the host side:
+
+    * ``sum`` shifts values by their minimum, splits them into 8-bit limbs
+      and sums limbs exactly (see :data:`_SUM_LIMB_BITS`); the host
+      recombines limbs and the shift in uint64, i.e. modulo 2**64 like
+      NumPy's int64 sum.
+    * ``max``/``min`` shift values into int32 when their range is below
+      2**32 (an order-preserving map) and shift the result back.
+
+    Returns None when 32 bits cannot hold the operation exactly — a value
+    range of 2**32 or more (max/min), or more than
+    :data:`_SUM_MAX_ROWS` rows in one segment (sum); the caller then runs
+    XLA's exact ``segment_*`` under x64.  ``seg`` holds the sorted
+    segment id of every row.
     """
-    jax, jnp, enable_x64 = _import_jax()
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     n, c = vals.shape
-    init = _op_init(op, vals.dtype)
-    pad = (-n) % block
-    if pad:
-        seg = np.concatenate([seg, np.full(pad, n_segments, seg.dtype)])
-        vals = np.concatenate([vals, np.full((pad, c), init, vals.dtype)])
-    seg = seg.astype(np.int32)
-    nb = len(seg) // block
-
-    def kernel(seg_ref, val_ref, out_ref, acc_ref):
-        bi = pl.program_id(0)
-
-        @pl.when(bi == 0)
-        def _init():
-            acc_ref[...] = jnp.full_like(acc_ref, init)
-
-        sids = seg_ref[...]  # (block,)
-        rows = val_ref[...]  # (block, c)
-        onehot = sids[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, n_segments), 1
+    vmin, vmax = int(vals.min()), int(vals.max())
+    counts = np.bincount(seg, minlength=n_segments)
+    if op == "sum":
+        if int(counts.max()) > _SUM_MAX_ROWS:
+            return None
+        u = vals.view(np.uint64) - np.uint64(vmin % (1 << 64))
+        k = max(1, -(-int(u.max()).bit_length() // _SUM_LIMB_BITS))
+        bits = [np.uint64(_SUM_LIMB_BITS * j) for j in range(k)]
+        host = np.stack([(u >> b) & np.uint64(255) for b in bits]).astype(np.int32)
+    else:
+        if vmax - vmin >= (1 << 32):
+            return None
+        shift = vmin + (1 << 31)
+        host = (vals - shift).astype(np.int32)[None]
+        k = 1
+    lay = _seg_layout(seg, n_segments)
+    c_pad = -(-c // 128) * 128
+    if c_pad > _SEG_COLS:
+        c_pad = -(-c_pad // _SEG_COLS) * _SEG_COLS
+    padded = np.zeros((k, lay["n_rows"], c_pad), np.int32)
+    padded[:, lay["dest"], :c] = host
+    fn = _seg_kernel(op, k, lay["n_rows"], lay["n_sb"], c_pad, interpret)
+    out = np.asarray(
+        fn(
+            lay["sb_of_rb"],
+            lay["first"],
+            lay["lo"],
+            lay["hi"],
+            lay["sids"],
+            padded if op == "sum" else padded[0],
         )
-        hit = onehot[:, :, None]  # (block, n_segments, 1)
-        if op == "sum":
-            acc_ref[...] += jnp.sum(jnp.where(hit, rows[:, None, :], 0), axis=0)
-        elif op == "max":
-            acc_ref[...] = jnp.maximum(
-                acc_ref[...],
-                jnp.max(jnp.where(hit, rows[:, None, :], init), axis=0),
-            )
-        else:  # min
-            acc_ref[...] = jnp.minimum(
-                acc_ref[...],
-                jnp.min(jnp.where(hit, rows[:, None, :], init), axis=0),
-            )
-
-        @pl.when(bi == pl.num_programs(0) - 1)
-        def _emit():
-            out_ref[...] = acc_ref[...]
-
-    with enable_x64():
-        out = pl.pallas_call(
-            kernel,
-            grid=(nb,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i: (i,)),
-                pl.BlockSpec((block, c), lambda i: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((n_segments, c), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((n_segments, c), vals.dtype),
-            scratch_shapes=[pltpu.VMEM((n_segments, c), jnp.dtype(vals.dtype))],
-            interpret=interpret,
-        )(seg, vals)
-        return np.asarray(out)
+    )
+    if op == "sum":
+        acc = (counts.astype(np.uint64) * np.uint64(vmin % (1 << 64)))[:, None]
+        acc = np.broadcast_to(acc, (n_segments, c)).copy()
+        for j in range(k):
+            part = out[j, :n_segments, :c].astype(np.uint64)
+            acc += part << np.uint64(_SUM_LIMB_BITS * j)
+        return acc.view(np.int64)
+    return out[:n_segments, :c].astype(np.int64) + shift
 
 
 class JaxBackend(ReduceBackend):
-    """jax.jit reductions; x64 is enabled inside every call, never globally.
+    """jax reductions on the default device; x64 is scoped to the calls
+    that need it, never enabled process-wide.
 
-    ``use_pallas=None`` auto-enables the Pallas segmented-reduce kernel on
-    TPU only; ``interpret=None`` runs Pallas in interpret mode off-TPU so
-    the kernel stays testable on CPU.  Construction raises
-    :class:`BackendUnavailable` when jax is missing or x64 cannot be
-    enabled — :func:`resolve_backend` turns that into a warning + NumPy
-    fallback.
+    On a TPU the segmented reductions always run the compiled Pallas
+    kernel.  Elsewhere they run XLA's ``segment_*`` ops, unless
+    ``interpret=True`` asks for the Pallas kernel in interpret mode — the
+    way CPU tests check the kernel.  Interpret mode on a TPU is refused.
+    Construction raises :class:`BackendUnavailable` when jax is missing
+    or x64 cannot be enabled.
     """
 
     name = "jax"
 
-    def __init__(
-        self,
-        use_pallas: Optional[bool] = None,
-        interpret: Optional[bool] = None,
-    ):
+    def __init__(self, interpret: bool = False):
         try:
             self._jax, self._jnp, self._enable_x64 = _import_jax()
-        except Exception as e:
+        except ImportError as e:
             raise BackendUnavailable(f"jax is not importable: {e!r}") from e
         if not _x64_ok():
             raise BackendUnavailable(
                 "jax x64 mode is unavailable; exact int64 reductions need it"
             )
-        on_tpu = self._jax.default_backend() == "tpu"
-        self.use_pallas = on_tpu if use_pallas is None else bool(use_pallas)
-        self.interpret = (not on_tpu) if interpret is None else bool(interpret)
+        self.platform = self._jax.default_backend()
+        if interpret and self.platform == "tpu":
+            raise ValueError("Pallas interpret mode is for CPU tests, not the TPU")
+        self.interpret = bool(interpret)
+        self.use_pallas = self.platform == "tpu" or self.interpret
 
     # -- exact int64 matmul -------------------------------------------------
     def matmul(self, w: np.ndarray, grid: np.ndarray) -> np.ndarray:
@@ -665,31 +810,17 @@ class JaxBackend(ReduceBackend):
         r = grid.shape[1]
         if g == 0 or s == 0 or r == 0:
             return np.zeros((g, r), np.int64)
-        if int(w.min()) < 0 or int(grid.min()) < 0:
-            return w @ grid  # profiler weights are non-negative by contract
-        plan = _limb_plan(int(w.max()), int(grid.max()), s)
-        if plan is None:  # pragma: no cover - beyond any int64-valid input
-            return w @ grid
-        ta, ka, tb, kb = plan
-        with self._enable_x64():
-            out = _limb_dot_fn(ka, kb, ta, tb)(
-                _limbs(w, ta, ka),
-                _limbs(grid, tb, kb),
-            )
-            return np.asarray(out)
+        return _limb_matmul(w, grid)
 
     # -- segmented reductions -----------------------------------------------
     def _segment_apply(self, vals: np.ndarray, seg: np.ndarray, nseg: int, op):
-        if self.use_pallas:
+        if self.use_pallas and np.issubdtype(vals.dtype, np.integer):
             flat = vals if vals.ndim == 2 else vals[:, None]
             out = _pallas_segment_reduce(
-                flat,
-                seg,
-                nseg,
-                op,
-                interpret=self.interpret,
+                flat.astype(np.int64), seg, nseg, op, interpret=self.interpret
             )
-            return out if vals.ndim == 2 else out[:, 0]
+            if out is not None:
+                return out if vals.ndim == 2 else out[:, 0]
         jax = self._jax
         fns = {
             "sum": jax.ops.segment_sum,
@@ -813,14 +944,13 @@ def resolve_backend(
 
     Priority: explicit ``backend`` argument, then a :func:`use_backend`
     thread-local override, then the ``REPRO_BACKEND`` environment variable,
-    then ``"numpy"``.  ``"jax"`` falls back to NumPy **with a warning**
-    when jax is missing or x64 cannot be enabled; an unknown explicit name
-    raises ``ValueError``, an unknown environment/override value warns and
-    falls back.
+    then ``"numpy"``.  Nothing falls back: an unknown name (from any
+    source) raises ``ValueError``, and ``"jax"`` raises
+    :class:`BackendUnavailable` when jax is missing or x64 cannot be
+    enabled.
     """
     if isinstance(backend, ReduceBackend):
         return backend
-    explicit = backend is not None
     name = backend
     if name is None:
         override = getattr(_tls, "override", None)
@@ -833,27 +963,11 @@ def resolve_backend(
         return _instance("numpy")
     name = str(name).strip().lower()
     if name not in available_backends():
-        if explicit:
-            raise ValueError(
-                f"unknown reduction backend: {backend!r} "
-                f"(expected one of {available_backends()})"
-            )
-        warnings.warn(
-            f"{BACKEND_ENV}={name!r} is not a known reduction backend "
-            f"{available_backends()}; falling back to numpy",
-            stacklevel=2,
+        raise ValueError(
+            f"unknown reduction backend: {backend or name!r} "
+            f"(expected one of {available_backends()}; {BACKEND_ENV} "
+            f"is read when no backend is passed)"
         )
-        return _instance("numpy")
-    if name == "jax":
-        try:
-            return _instance("jax")
-        except BackendUnavailable as e:
-            warnings.warn(
-                f"jax reduction backend unavailable ({e}); "
-                "falling back to the numpy reference",
-                stacklevel=2,
-            )
-            return _instance("numpy")
     return _instance(name)
 
 

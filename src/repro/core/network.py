@@ -37,10 +37,11 @@ from typing import Optional, Union
 import numpy as np
 
 from repro.core.backend import ReduceBackend, resolve_backend
+from repro.core.devices import MODELED_DEVICE_KIND, chip_peaks
 
-#: Default per-link bandwidth — the TPU v5e ICI figure the runner's
-#: roofline model uses (``repro.benchpark.runner.LINK_BW``).
-DEFAULT_LINK_BW = 50e9
+#: Default per-link bandwidth — one ICI link of the modeled chip (the
+#: figure the runner's modeled step seconds use too).
+DEFAULT_LINK_BW = chip_peaks(MODELED_DEVICE_KIND).ici_link_bytes_per_s
 DEFAULT_LATENCY_S = 1e-6
 
 

@@ -36,9 +36,9 @@ statistic, no per-event/per-op Python in either.
 
 Backend contract (see :mod:`repro.core.backend`): the kernels live in a
 swappable reduction backend selected by ``backend=`` / ``REPRO_BACKEND``
-(``"numpy"`` reference, or ``"jax"`` — jit-compiled with x64 enabled inside
-the backend and an optional Pallas segmented-reduce kernel that auto-enables
-on TPU).  Boundaries are NumPy arrays in both directions; every int64
+(``"numpy"`` reference, or ``"jax"`` — exact int8-limb matmuls on the
+default device, and a Pallas segmented-reduce kernel that runs compiled on
+TPU).  Boundaries are NumPy arrays in both directions; every int64
 count/byte path is **exact**, so profiles are bit-identical across backends.
 Host NumPy keeps the O(rows) scatters/orderings; the backend owns the
 O(G x S x Rmax) weight-grid matmuls and the peer-set dedup that dominate at

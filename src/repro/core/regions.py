@@ -32,9 +32,8 @@ Recorder and region-stack state are **thread-local**: concurrent traces
 (e.g. the benchpark runner profiling independent scaling points in a
 thread pool) each see their own recorder and cannot cross-attribute
 events.  The shard_map/mesh machinery the instrumented collectives run
-under is provided by :mod:`repro.core.compat`, which keeps this layer
-working across JAX API churn (0.4.x through >= 0.5) — see compat's module
-docstring for the supported versions and contract.
+under is provided by :mod:`repro.core.compat`, which keeps JAX API churn
+out of this layer — see compat's module docstring for the contract.
 
 Structure-interned columnar trace store (profiling data model)
 --------------------------------------------------------------

@@ -16,16 +16,18 @@ from repro.configs import registry            # noqa: E402
 from repro.core import compat                 # noqa: E402
 from repro.configs.base import SHAPES, model_flops  # noqa: E402
 from repro.core.hlo import scan_hlo_collectives  # noqa: E402
+from repro.core.devices import MODELED_DEVICE_KIND, chip_peaks  # noqa: E402
 from repro.core.hlo_cost import analyze_cost  # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_shape_dict  # noqa: E402
 from repro.parallel.context import parallel_context  # noqa: E402
 from repro.parallel.sharding import default_plan     # noqa: E402
 from repro.train import steps as S                   # noqa: E402
 
-# TPU v5e hardware model (assignment constants)
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / ICI link
+# TPU v5e hardware model (repro.core.devices)
+_PEAKS = chip_peaks(MODELED_DEVICE_KIND)
+PEAK_FLOPS = _PEAKS.flops_bf16           # bf16 / chip
+HBM_BW = _PEAKS.hbm_bytes_per_s          # bytes/s / chip
+LINK_BW = _PEAKS.ici_link_bytes_per_s    # bytes/s / ICI link
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
                            "..", "..", "..", "benchmarks", "results",

@@ -5,8 +5,7 @@ never touches jax device state.  The dry-run process sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import (see dryrun.py's first two lines).
 
-Mesh construction goes through :mod:`repro.core.compat` so this module
-imports and runs on both jax 0.4.x (no ``AxisType``) and >= 0.5.
+Mesh construction goes through :mod:`repro.core.compat`.
 """
 
 from __future__ import annotations

@@ -1,18 +1,17 @@
 """Backend selection, exactness, and dedup-strategy unit tests.
 
 Covers the :mod:`repro.core.backend` substrate on its own terms:
-``REPRO_BACKEND`` env parsing and the graceful NumPy fallback when jax is
-missing or x64 is off (a warning, never a crash), dispatch from
+``REPRO_BACKEND`` env parsing, the errors raised when jax is missing or x64
+is off or a name is unknown (never a silent NumPy fallback), dispatch from
 ``CommPatternProfiler`` / ``Frame.agg`` into the selected backend, the
-exact-int64 matmul (single-f64 and limb-decomposed plans, negative-input
-fallback), and the peer-set dedup strategy split (dense bitmap / chunked
-bitmap / sort-based ``np.unique``) that replaced the historical
-``G * Rmax * stride`` single-allocation bitmap.  End-to-end bit-identical
+exact-int64 matmul over int8 limbs (negative inputs included), the Pallas
+segmented reduce in interpret mode, and the peer-set dedup strategy split
+(dense bitmap / chunked bitmap / sort-based ``np.unique``) that replaced
+the historical ``G * Rmax * stride`` single-allocation bitmap.  End-to-end
+bit-identical
 profile parity lives in ``test_backend_parity.py``; timing assertions in
 ``test_backend_perf.py``.
 """
-
-import warnings
 
 import numpy as np
 import pytest
@@ -24,7 +23,8 @@ from repro.core.backend import (
     JaxBackend,
     NumpyBackend,
     _dedup_strategy,
-    _limb_plan,
+    _limbs,
+    _n_limbs,
     _pair_counts_numpy,
     resolve_backend,
     segment_spans,
@@ -81,9 +81,10 @@ def test_resolve_env_normalizes_whitespace_and_case(monkeypatch):
 
 
 def test_resolve_unknown_env_warns_and_falls_back(monkeypatch):
+    """An unknown REPRO_BACKEND value raises; nothing falls back to NumPy."""
     monkeypatch.setenv(BACKEND_ENV, "cuda")
-    with pytest.warns(UserWarning, match="not a known reduction backend"):
-        assert isinstance(resolve_backend(), NumpyBackend)
+    with pytest.raises(ValueError, match="unknown reduction backend"):
+        resolve_backend()
 
 
 def test_resolve_unknown_explicit_name_raises():
@@ -130,7 +131,7 @@ def test_use_backend_accepts_instances():
 
 
 # ---------------------------------------------------------------------------
-# Graceful fallback: jax missing / x64 unavailable -> warning + numpy
+# No fallback: jax missing / x64 unavailable -> BackendUnavailable
 # ---------------------------------------------------------------------------
 
 
@@ -140,15 +141,15 @@ def test_jax_missing_falls_back_with_warning(monkeypatch):
 
     monkeypatch.setattr(B, "_import_jax", boom)
     monkeypatch.setattr(B, "_instances", {})  # bypass the cached instance
-    with pytest.warns(UserWarning, match="falling back to the numpy"):
-        assert isinstance(resolve_backend("jax"), NumpyBackend)
+    with pytest.raises(BackendUnavailable, match="not importable"):
+        resolve_backend("jax")
 
 
 def test_x64_off_falls_back_with_warning(monkeypatch):
     monkeypatch.setattr(B, "_x64_ok", lambda: False)
     monkeypatch.setattr(B, "_instances", {})
-    with pytest.warns(UserWarning, match="falling back to the numpy"):
-        assert isinstance(resolve_backend("jax"), NumpyBackend)
+    with pytest.raises(BackendUnavailable, match="x64"):
+        resolve_backend("jax")
 
 
 def test_jax_backend_ctor_raises_backend_unavailable(monkeypatch):
@@ -158,13 +159,12 @@ def test_jax_backend_ctor_raises_backend_unavailable(monkeypatch):
 
 
 def test_fallback_still_profiles(monkeypatch):
+    """A profile asked of an unavailable jax backend fails loudly instead
+    of quietly reducing on NumPy."""
     monkeypatch.setattr(B, "_x64_ok", lambda: False)
     monkeypatch.setattr(B, "_instances", {})
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        prof = CommPatternProfiler.from_recorder(_small_recorder(), backend="jax")
-    ref = CommPatternProfiler.from_recorder(_small_recorder())
-    assert prof.to_json() == ref.to_json()
+    with pytest.raises(BackendUnavailable):
+        CommPatternProfiler.from_recorder(_small_recorder(), backend="jax")
 
 
 # ---------------------------------------------------------------------------
@@ -216,20 +216,22 @@ def test_env_default_reaches_profiler(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Exact int64 matmul (the jax backend's f64 / limb-decomposed dots)
+# Exact int64 matmul (the jax backend's int8-limb dots)
 # ---------------------------------------------------------------------------
 
 
 def _jax_be() -> JaxBackend:
-    return resolve_backend("jax")
+    be = resolve_backend("jax")
+    assert isinstance(be, JaxBackend)
+    return be
 
 
 @pytest.mark.parametrize(
     "wmax,gmax",
     [
-        (5, 7),  # trivially exact in one f64 dot
-        (1 << 20, 1 << 24),  # still one dot: product < 2**53
-        (1 << 30, 1 << 30),  # needs limb decomposition
+        (5, 7),  # one limb each side
+        (1 << 20, 1 << 24),  # three and four limbs
+        (1 << 30, 1 << 30),  # five limbs each side
         (1 << 59, 1),  # extreme single-side magnitude
     ],
 )
@@ -244,11 +246,17 @@ def test_matmul_exact_vs_numpy(wmax, gmax):
     np.testing.assert_array_equal(got, want)
 
 
-def test_matmul_negative_inputs_fall_back_exactly():
+def test_matmul_negative_inputs_fall_back_exactly(monkeypatch):
+    """Negative inputs stay on the device path (signed top limb) and
+    match NumPy exactly, int64 wrap-around included."""
+    calls = _spy(monkeypatch, B, "_limb_matmul")
     rng = np.random.default_rng(3)
     w = rng.integers(-50, 50, size=(4, 6), dtype=np.int64)
     g = rng.integers(-50, 50, size=(6, 5), dtype=np.int64)
     np.testing.assert_array_equal(_jax_be().matmul(w, g), w @ g)
+    big = rng.integers(-(1 << 62), 1 << 62, size=(3, 9), dtype=np.int64)
+    np.testing.assert_array_equal(_jax_be().matmul(big, big.T), big @ big.T)
+    assert len(calls) == 2
 
 
 def test_matmul_empty_shapes():
@@ -260,16 +268,21 @@ def test_matmul_empty_shapes():
 
 
 def test_limb_plan_regimes():
-    amax = bmax = 1 << 30
-    assert _limb_plan(5, 7, 13) == (64, 1, 64, 1)  # single exact dot
-    plan = _limb_plan(amax, bmax, 13)  # needs a split
-    assert plan is not None and plan[1] * plan[3] > 1
-    # every plan keeps partial f64 products exact (an unsplit side, k == 1,
-    # contributes its full magnitude)
-    ta, ka, tb, kb = plan
-    a_limb = (1 << ta) - 1 if ka > 1 else amax
-    b_limb = (1 << tb) - 1 if kb > 1 else bmax
-    assert a_limb * b_limb * 13 < (1 << 53)
+    """Limb counts grow 7 bits at a time; limbs are int8 and recombine
+    exactly (the top limb carries the sign)."""
+    assert _n_limbs(np.array([0, 127])) == 1
+    assert _n_limbs(np.array([-128, 5])) == 1
+    assert _n_limbs(np.array([128])) == 2
+    assert _n_limbs(np.array([np.iinfo(np.int64).min])) == 9
+    assert _n_limbs(np.array([np.iinfo(np.int64).max])) == 9
+    rng = np.random.default_rng(4)
+    v = rng.integers(-(1 << 62), 1 << 62, size=64, dtype=np.int64)
+    k = _n_limbs(v)
+    limbs = _limbs(v, k)
+    assert limbs.dtype == np.int8 and limbs.shape == (k, 64)
+    assert (limbs[:-1] >= 0).all()
+    back = sum(limbs[i].astype(object) * (1 << (7 * i)) for i in range(k))
+    assert [int(x) for x in back] == [int(x) for x in v]
 
 
 # ---------------------------------------------------------------------------
@@ -456,33 +469,81 @@ def test_jax_backend_delegates_past_sketch_extent():
 # ---------------------------------------------------------------------------
 
 
+def _pallas_be(monkeypatch) -> tuple:
+    """Interpret-mode backend plus a log of kernel runs that returned a
+    result (None means the op was routed to XLA's segment ops)."""
+    ran = []
+    orig = B._pallas_segment_reduce
+
+    def spy(*a, **kw):
+        out = orig(*a, **kw)
+        ran.append(out is not None)
+        return out
+
+    monkeypatch.setattr(B, "_pallas_segment_reduce", spy)
+    return JaxBackend(interpret=True), ran
+
+
 @pytest.mark.parametrize("ufunc", [np.add, np.maximum, np.minimum])
-def test_pallas_segment_reduce_parity(ufunc):
-    be = JaxBackend(use_pallas=True, interpret=True)
+def test_pallas_segment_reduce_parity(ufunc, monkeypatch):
+    be, ran = _pallas_be(monkeypatch)
     rng = np.random.default_rng(21)
     key = np.sort(rng.integers(0, 9, 500)).astype(np.int64)
-    col = rng.integers(0, 1 << 40, 500).astype(np.int64)
+    # far from zero but within a 2**32 range: max/min shift into int32
+    col = (1 << 40) + rng.integers(0, 1 << 31, 500).astype(np.int64)
     order, _, starts, _ = segment_spans(key)
     want = NumpyBackend().segment_reduce(col, order, starts, ufunc)
     got = be.segment_reduce(col, order, starts, ufunc)
     assert got.dtype == want.dtype
     np.testing.assert_array_equal(got, want)
+    assert ran == [True], "the Pallas kernel did not produce the result"
 
 
 @pytest.mark.parametrize("ufunc", [np.add, np.maximum, np.minimum])
-def test_pallas_block_reduce_parity(ufunc):
-    be = JaxBackend(use_pallas=True, interpret=True)
+def test_pallas_block_reduce_parity(ufunc, monkeypatch):
+    be, ran = _pallas_be(monkeypatch)
     rng = np.random.default_rng(22)
-    key = np.sort(rng.integers(0, 6, 300)).astype(np.int64)
-    grid = rng.integers(0, 1 << 30, (300, 5)).astype(np.int64)
+    # more than one segment block, row tile and column tile
+    key = np.sort(rng.integers(0, 300, 3000)).astype(np.int64)
+    grid = rng.integers(-(1 << 30), 1 << 30, (3000, 700)).astype(np.int64)
     _, _, starts, ends = segment_spans(key)
     want = NumpyBackend().block_reduce(grid, starts, ends, ufunc)
     got = be.block_reduce(grid, starts, ends, ufunc)
     np.testing.assert_array_equal(got, want)
+    assert ran == [True], "the Pallas kernel did not produce the result"
 
 
-def test_pallas_backend_profiles_identically():
-    be = JaxBackend(use_pallas=True, interpret=True)
+@pytest.mark.parametrize("ufunc", [np.add, np.maximum, np.minimum])
+def test_pallas_wide_values_stay_exact(ufunc, monkeypatch):
+    """Sums over the full int64 range wrap like NumPy's; max/min over a
+    range 32 bits cannot hold are routed to XLA's exact segment ops."""
+    be, ran = _pallas_be(monkeypatch)
+    rng = np.random.default_rng(23)
+    key = np.sort(rng.integers(0, 5, 400)).astype(np.int64)
+    col = rng.integers(np.iinfo(np.int64).min, np.iinfo(np.int64).max, 400)
+    order, _, starts, _ = segment_spans(key)
+    want = NumpyBackend().segment_reduce(col, order, starts, ufunc)
+    np.testing.assert_array_equal(be.segment_reduce(col, order, starts, ufunc), want)
+    assert ran == [ufunc is np.add]
+
+
+def test_pallas_backend_profiles_identically(monkeypatch):
+    calls = _spy(monkeypatch, JaxBackend, "matmul")
+    be = JaxBackend(interpret=True)
     prof = CommPatternProfiler.from_recorder(_small_recorder(), backend=be)
     ref = CommPatternProfiler.from_recorder(_small_recorder())
     assert prof.to_json() == ref.to_json()
+    assert calls, "the profile never reached the jax backend"
+
+
+def test_interpret_mode_is_explicit_and_never_on_tpu(monkeypatch):
+    """Off TPU the default backend runs XLA's segment ops, not Pallas;
+    asking for interpret mode on a TPU is refused."""
+    assert JaxBackend().use_pallas is False
+    assert JaxBackend(interpret=True).use_pallas is True
+    jax, _, _ = B._import_jax()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert JaxBackend().use_pallas is True
+    assert JaxBackend().interpret is False
+    with pytest.raises(ValueError, match="interpret"):
+        JaxBackend(interpret=True)

@@ -166,3 +166,38 @@ def test_out_dir_still_written_on_cache_hit(tmp_path):
     assert names == ["kripke-cache-test-00002.json",
                      "kripke-cache-test-00004.json",
                      "kripke-cache-test-00008.json"]
+
+
+# ---------------------------------------------------------------------------
+# One process per chip: process pools trace on the host CPU only
+# ---------------------------------------------------------------------------
+
+
+def test_process_workers_are_cpu_only():
+    """Pool workers pin JAX to the CPU before anything initializes a
+    backend, so they never reach for the parent's accelerator."""
+    import os
+    from concurrent.futures import ProcessPoolExecutor
+
+    with ProcessPoolExecutor(
+        max_workers=1,
+        mp_context=runner._pool_mp_context(),
+        initializer=runner._trace_only_worker,
+    ) as ex:
+        assert ex.submit(os.getenv, "JAX_PLATFORMS").result(timeout=120) == "cpu"
+
+
+def test_process_executor_refuses_device_reduction(monkeypatch):
+    """A jax reduction on an accelerator cannot run on the CPU-only
+    workers; asking for one under executor='process' raises before any
+    pool starts instead of reducing on the workers' CPU."""
+    import jax
+    import pytest
+
+    from repro.core import backend as B
+
+    monkeypatch.setattr(B, "_instances", {})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(runner, "ProcessPoolExecutor", _bomb)
+    with pytest.raises(ValueError, match="executor='process'"):
+        run_experiment(_spec(), verbose=False, executor="process", backend="jax")

@@ -1,0 +1,142 @@
+"""Compiles for a described TPU v5e: the main path's kernels and app steps.
+
+Nothing here runs on a chip.  Each test compiles one program at its real
+size for a v5e that is described, not attached (``jax.experimental.
+topologies``), so what the chip's compiler would refuse — an unaligned
+block, too much VMEM, a 64-bit type inside a kernel, a program over the
+chip's memory — fails here at no chip time.  The topology is described
+inside a module-scoped fixture, never at import, so test workers collect
+the same tests and only the worker given this file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.core import backend as B
+from repro.core import compat
+from repro.core.devices import chip_peaks
+
+#: Rank columns the kernel is compiled at (the 8192-rank sweep point).
+RANKS = 8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _hbm(topo) -> float:
+    return chip_peaks(topo.devices[0].device_kind).hbm_bytes
+
+
+@pytest.mark.parametrize("op,k", [("sum", 2), ("max", 1), ("min", 1)])
+def test_pallas_segment_reduce_compiles_for_v5e(topo, one_chip, op, k):
+    """The segmented reduce at 8192 rank columns is a Mosaic kernel, with
+    VMEM use fixed by its tiles and nothing beyond its operands in HBM."""
+    n_rows, n_sb = 16 * B._SEG_ROWS, 2
+    n_rb = n_rows // B._SEG_ROWS
+    fn = B._seg_kernel(op, k, n_rows, n_sb, RANKS, False)
+    i32 = jnp.int32
+    table = [_sds((n_rb,), i32, one_chip) for _ in range(4)]
+    vals = (k, n_rows, RANKS) if op == "sum" else (n_rows, RANKS)
+    compiled = fn.lower(
+        *table, _sds((n_rows,), i32, one_chip), _sds(vals, i32, one_chip)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    out_rows = n_sb * B._SEG_BLOCK
+    assert mem.argument_size_in_bytes >= 4 * int(np.prod(vals))
+    assert mem.output_size_in_bytes == 4 * k * out_rows * RANKS
+    assert mem.temp_size_in_bytes <= 4 * k * out_rows * RANKS
+
+
+def test_limb_dot_compiles_for_v5e(topo, one_chip):
+    """The exact matmul's int8 limb dot lowers to an s32 MXU convolution
+    and fits the chip at a 64-region x 4096-struct x 8192-rank reduction
+    with five limbs of weights."""
+    g, s, ka = 64, 4096, 5
+    compiled = (
+        B._limb_dot_fn()
+        .lower(
+            _sds((ka * g, s), jnp.int8, one_chip),
+            _sds((s, RANKS), jnp.int8, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "s32[320,8192]" in text and "convolution" in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * ka * g * RANKS
+    assert mem.temp_size_in_bytes < _hbm(topo) / 4
+
+
+def _app_bytes(compiled) -> int:
+    mem = compiled.memory_analysis()
+    return (
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+
+
+def test_kripke_tioga_sweep_compiles_on_one_chip(topo):
+    """The Tioga (2,2,2) global problem on one chip — 32x64x64 zones, 6x6
+    sets, 4x4 dirs/groups, 2 unfused octants — fits in its HBM with the
+    regions in the program."""
+    from repro.apps import kripke
+    from repro.apps.stencil import Decomp3D
+
+    mesh = compat.make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
+    cfg = kripke.KripkeConfig(
+        decomp=Decomp3D(1, 1, 1),
+        nx=32,
+        ny=64,
+        nz=64,
+        n_octants=2,
+        fuse_messages=False,
+    )
+    q = _sds((6, 6, 32, 64, 64, 4, 4), jnp.float32, NamedSharding(mesh, P()))
+    compiled = jax.jit(kripke.distributed_sweep(cfg, mesh)).lower(q).compile()
+    assert "commr::main" in compiled.as_text()
+    q_bytes = 4 * 6 * 6 * 32 * 64 * 64 * 4 * 4
+    assert compiled.memory_analysis().argument_size_in_bytes >= q_bytes
+    assert _app_bytes(compiled) < _hbm(topo)
+
+
+@pytest.mark.parametrize("decomp", [(1, 1, 1), (2, 2, 1)])
+def test_laghos_step_compiles(topo, decomp):
+    """Laghos 512x512 strong, 2 steps, on one chip and across four."""
+    from repro.apps import laghos
+    from repro.apps.stencil import Decomp3D
+
+    n = int(np.prod(decomp))
+    mesh = compat.make_mesh(decomp, ("x", "y", "z"), devices=topo.devices[:n])
+    cfg = laghos.LaghosConfig(decomp=Decomp3D(*decomp), nx=512, ny=512, n_steps=2)
+    s = _sds((512, 512), jnp.float32, NamedSharding(mesh, P()))
+    state = dict(rho=s, e=s, vx=s, vy=s)
+    compiled = jax.jit(laghos.run_steps(cfg, mesh)).lower(state).compile()
+    text = compiled.as_text()
+    assert "commr::halo_exchange" in text
+    if n > 1:
+        assert "collective-permute" in text
+    assert _app_bytes(compiled) < _hbm(topo) / 100
