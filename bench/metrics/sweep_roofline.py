@@ -1,0 +1,16 @@
+"""Share of the HBM roofline that one step of the sweep reaches.
+
+The least time is the algorithm's compulsory bytes per chip (the source
+read once, the result written once, counted from the configuration's
+shapes) over the chip's published HBM bandwidth; it is divided by the
+device time of a step, the chips' mean busy time in the traced window over
+the steps in it.
+"""
+
+
+def read(obs):
+    trace, steps = obs.get("trace"), obs.get("steps")
+    if trace is None or not steps or "compulsory_bytes_per_chip" not in obs:
+        return None
+    least_s = obs["compulsory_bytes_per_chip"] / obs["peaks"].hbm_bytes_per_s
+    return 100.0 * least_s / (trace.mean_busy_s / steps)
