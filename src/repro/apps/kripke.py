@@ -79,31 +79,33 @@ def _octant_signs(octant: int) -> tuple:
 def _axis_recurrence(src, inflow, axis: int, w: float, sig: float, sign: int):
     """psi_i = a * psi_{i-1} + b_i with a = w/(sig+w), b = src/(sig+w);
     descending directions sweep the axis in reverse.  ``inflow`` (the
-    upwind face, size 1 along ``axis``) is psi_{-1}.
+    upwind face, size 1 along ``axis``) is psi_{-1}.  ``axis`` is 2, 3 or 4
+    (x, y, z); the pass runs under the named scope ``kripke.scan_<x|y|z>``.
 
     A sequential scan along the axis: a block seeded with its upwind
     neighbour's face repeats the single-domain arithmetic exactly.  (A
     reversed ``lax.associative_scan`` computed wrong values on TPU v5e at
     the Tioga global problem, 6x6x32x64x64x4x4, with jax 0.9.0.)
     """
-    a = w / (sig + w)
-    b = jnp.moveaxis(src / (sig + w), axis, 0)
-    inflow = jnp.moveaxis(inflow, axis, 0)[0]
+    with jax.named_scope(f"kripke.scan_{AXIS_NAMES[axis - 2]}"):
+        a = w / (sig + w)
+        b = jnp.moveaxis(src / (sig + w), axis, 0)
+        inflow = jnp.moveaxis(inflow, axis, 0)[0]
 
-    def step(prev, b_i):
-        psi = a * prev + b_i
-        return psi, psi
+        def step(prev, b_i):
+            psi = a * prev + b_i
+            return psi, psi
 
-    # the upwind zone seeds the carry, so it has the type of the rows
-    if sign > 0:
-        first = a * inflow + b[0]
-        _, rest = lax.scan(step, first, b[1:])
-        psi = jnp.concatenate([first[None], rest])
-    else:
-        first = a * inflow + b[-1]
-        _, rest = lax.scan(step, first, b[:-1], reverse=True)
-        psi = jnp.concatenate([rest, first[None]])
-    return jnp.moveaxis(psi, 0, axis)
+        # the upwind zone seeds the carry, so it has the type of the rows
+        if sign > 0:
+            first = a * inflow + b[0]
+            _, rest = lax.scan(step, first, b[1:])
+            psi = jnp.concatenate([first[None], rest])
+        else:
+            first = a * inflow + b[-1]
+            _, rest = lax.scan(step, first, b[:-1], reverse=True)
+            psi = jnp.concatenate([rest, first[None]])
+        return jnp.moveaxis(psi, 0, axis)
 
 
 def _local_sweep(q, in_x, in_y, in_z, cfg: KripkeConfig, signs=(1, 1, 1)):

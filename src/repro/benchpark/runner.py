@@ -111,6 +111,7 @@ from repro.core.faultinject import (
 )
 from repro.core.profiler import CommPatternProfiler, CommProfile, trace_observer
 from repro.core.thicket import Frame
+from repro.core.tracing import span
 
 #: Peaks of the modeled system (TPU v5e) behind the modeled step seconds.
 _PEAKS = chip_peaks(MODELED_DEVICE_KIND)
@@ -744,75 +745,76 @@ def _trace_point(
     ``worker_crash@hard`` rule may ``os._exit`` instead of raising.
     Returns ``(pt, profile, cached)``.
     """
-    point = point_key(spec, pt)
-    with fault_context(f"{point}#a{attempt}|"):
-        fire_worker_faults(point, crash_safe=_crash_safe)
-        profile_fns = app_profile_fns()
-        meta = {
-            "app": spec.app,
-            "scaling": spec.scaling,
-            "experiment": spec.name,
-            "decomp": list(pt.decomp),
-            "system": spec.system,
-        }
-        key = cache.key(spec.app, cfg, pt.decomp) if cache else None
-        prof = cache.get(key) if cache else None
-        cached = prof is not None
-        holder: dict = {}
-        if cached:
-            # identical physics, this experiment's labels
-            prof.name = f"{spec.name}-{pt.n_ranks}"
-            prof.meta = meta
-        else:
-            ctx = use_backend(backend) if backend is not None else nullcontext()
-            obs = (
-                trace_observer(_make_live_observer(holder, live_shards))
-                if live_dir
-                else nullcontext()
-            )
-            with ctx, obs:
-                prof = profile_fns[spec.app](
-                    cfg, name=f"{spec.name}-{pt.n_ranks}", meta=meta
-                )
-        prof.meta["seconds"] = _roofline_seconds(spec.app, cfg, prof)
-        if live_dir:
-            # Publish only after the roofline stamp so shard meta finalizes
-            # to exactly the batch pipeline's profile bytes.
-            deltas = holder.get("deltas")
-            if deltas is None:  # cache hit (or an app bypassing tracing)
-                publish_shard(
-                    live_dir,
-                    point=point,
-                    seq=0,
-                    total=1,
-                    profile_json=prof.to_json(),
-                    name=prof.name,
-                    meta=prof.meta,
-                )
+    with span("point"):
+        point = point_key(spec, pt)
+        with fault_context(f"{point}#a{attempt}|"):
+            fire_worker_faults(point, crash_safe=_crash_safe)
+            profile_fns = app_profile_fns()
+            meta = {
+                "app": spec.app,
+                "scaling": spec.scaling,
+                "experiment": spec.name,
+                "decomp": list(pt.decomp),
+                "system": spec.system,
+            }
+            key = cache.key(spec.app, cfg, pt.decomp) if cache else None
+            prof = cache.get(key) if cache else None
+            cached = prof is not None
+            holder: dict = {}
+            if cached:
+                # identical physics, this experiment's labels
+                prof.name = f"{spec.name}-{pt.n_ranks}"
+                prof.meta = meta
             else:
-                for i, delta in enumerate(deltas):
+                ctx = use_backend(backend) if backend is not None else nullcontext()
+                obs = (
+                    trace_observer(_make_live_observer(holder, live_shards))
+                    if live_dir
+                    else nullcontext()
+                )
+                with ctx, obs:
+                    prof = profile_fns[spec.app](
+                        cfg, name=f"{spec.name}-{pt.n_ranks}", meta=meta
+                    )
+            prof.meta["seconds"] = _roofline_seconds(spec.app, cfg, prof)
+            if live_dir:
+                # Publish only after the roofline stamp so shard meta finalizes
+                # to exactly the batch pipeline's profile bytes.
+                deltas = holder.get("deltas")
+                if deltas is None:  # cache hit (or an app bypassing tracing)
                     publish_shard(
                         live_dir,
                         point=point,
-                        seq=i,
-                        total=len(deltas),
-                        summary=delta,
+                        seq=0,
+                        total=1,
+                        profile_json=prof.to_json(),
                         name=prof.name,
-                        replication=holder["replication"],
                         meta=prof.meta,
                     )
-        if cache and not cached:
-            cache.put(key, prof)
-    if verbose:  # stream progress as points finish
-        tot = sum(s.total_bytes_sent for s in prof.regions.values())
-        tag = " [cached]" if cached else ""
-        print(
-            f"  {spec.name} @ {pt.n_ranks:4d} ranks: "
-            f"{len(prof.regions)} regions, "
-            f"{tot:.3e} bytes sent{tag}",
-            flush=True,
-        )
-    return pt, prof, cached
+                else:
+                    for i, delta in enumerate(deltas):
+                        publish_shard(
+                            live_dir,
+                            point=point,
+                            seq=i,
+                            total=len(deltas),
+                            summary=delta,
+                            name=prof.name,
+                            replication=holder["replication"],
+                            meta=prof.meta,
+                        )
+            if cache and not cached:
+                cache.put(key, prof)
+        if verbose:  # stream progress as points finish
+            tot = sum(s.total_bytes_sent for s in prof.regions.values())
+            tag = " [cached]" if cached else ""
+            print(
+                f"  {spec.name} @ {pt.n_ranks:4d} ranks: "
+                f"{len(prof.regions)} regions, "
+                f"{tot:.3e} bytes sent{tag}",
+                flush=True,
+            )
+        return pt, prof, cached
 
 
 def _trace_point_in_worker(args) -> tuple:

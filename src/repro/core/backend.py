@@ -70,6 +70,8 @@ from typing import Optional, Union
 
 import numpy as np
 
+from repro.core.tracing import span
+
 #: Environment variable naming the default reduction backend.
 BACKEND_ENV = "REPRO_BACKEND"
 
@@ -387,8 +389,21 @@ def _pair_codes_numpy(
 # ---------------------------------------------------------------------------
 
 
+def _op_span(fn):
+    """Run a backend op inside a span named after it."""
+    name = fn.__name__
+
+    @functools.wraps(fn)
+    def op(*args, **kwargs):
+        with span(name):
+            return fn(*args, **kwargs)
+
+    return op
+
+
 class ReduceBackend:
-    """Interface every reduction backend implements (NumPy in, NumPy out)."""
+    """Interface every reduction backend implements (NumPy in, NumPy out).
+    Each op of a backend runs inside a span named after it."""
 
     name = "abstract"
 
@@ -426,22 +441,28 @@ class NumpyBackend(ReduceBackend):
 
     name = "numpy"
 
+    @_op_span
     def matmul(self, w: np.ndarray, grid: np.ndarray) -> np.ndarray:
         return w @ grid
 
+    @_op_span
     def block_reduce(self, grid, starts, ends, ufunc: np.ufunc) -> np.ndarray:
         return block_reduce(grid, starts, ends, ufunc)
 
+    @_op_span
     def segment_reduce(self, col, order, starts, ufunc: np.ufunc = np.add):
         return segment_reduce(col, order, starts, ufunc)
 
+    @_op_span
     def factorize(self, col: np.ndarray) -> tuple:
         uniq, first, inv = np.unique(col, return_index=True, return_inverse=True)
         return uniq, first.astype(np.int64), inv.reshape(-1).astype(np.int64)
 
+    @_op_span
     def pair_counts(self, group_ids, rows, peers, n_groups, rank_extent):
         return _pair_counts_numpy(group_ids, rows, peers, n_groups, rank_extent)
 
+    @_op_span
     def pair_codes(self, group_ids, rows, peers, n_groups) -> tuple:
         return _pair_codes_numpy(group_ids, rows, peers, n_groups)
 
@@ -504,9 +525,12 @@ def _limbs(arr: np.ndarray, k: int) -> np.ndarray:
 def _limb_dot_fn():
     """jit-compiled int8 x int8 -> int32 dot (exact on every backend)."""
     jax, jnp, _ = _import_jax()
-    return jax.jit(
-        lambda a, b: jax.lax.dot(a, b, preferred_element_type=jnp.int32)
-    )
+
+    def dot(a, b):
+        with jax.named_scope("repro.limb_dot"):
+            return jax.lax.dot(a, b, preferred_element_type=jnp.int32)
+
+    return jax.jit(dot)
 
 
 def _limb_matmul(w: np.ndarray, grid: np.ndarray) -> np.ndarray:
@@ -526,7 +550,9 @@ def _limb_matmul(w: np.ndarray, grid: np.ndarray) -> np.ndarray:
     dot = _limb_dot_fn()
     for lo in range(0, s, _LIMB_DOT_MAX_K):
         hi = min(s, lo + _LIMB_DOT_MAX_K)
-        part = np.asarray(dot(a[:, lo:hi], b[lo:hi])).astype(np.int64)
+        with span("device_roundtrip"):
+            part = np.asarray(dot(a[:, lo:hi], b[lo:hi]))
+        part = part.astype(np.int64)
         part = part.reshape(ka, g, kb, r).astype(np.uint64)
         for i in range(ka):
             for j in range(kb):
@@ -648,6 +674,7 @@ def _seg_kernel(op: str, k: int, n_rows: int, n_sb: int, c_pad: int, interpret: 
                 dimension_semantics=("parallel", "parallel", "arbitrary")
             ),
             interpret=interpret,
+            name="repro_segment_sum",
         )
 
         def run(sb_of_rb, first, lo, hi, sids, vals):
@@ -697,6 +724,7 @@ def _seg_kernel(op: str, k: int, n_rows: int, n_sb: int, c_pad: int, interpret: 
                 dimension_semantics=("parallel", "arbitrary")
             ),
             interpret=interpret,
+            name=f"repro_segment_{op}",
         )
 
         def run(sb_of_rb, first, lo, hi, sids, vals):
@@ -753,16 +781,17 @@ def _pallas_segment_reduce(
     padded = np.zeros((k, lay["n_rows"], c_pad), np.int32)
     padded[:, lay["dest"], :c] = host
     fn = _seg_kernel(op, k, lay["n_rows"], lay["n_sb"], c_pad, interpret)
-    out = np.asarray(
-        fn(
-            lay["sb_of_rb"],
-            lay["first"],
-            lay["lo"],
-            lay["hi"],
-            lay["sids"],
-            padded if op == "sum" else padded[0],
+    with span("device_roundtrip"):
+        out = np.asarray(
+            fn(
+                lay["sb_of_rb"],
+                lay["first"],
+                lay["lo"],
+                lay["hi"],
+                lay["sids"],
+                padded if op == "sum" else padded[0],
+            )
         )
-    )
     if op == "sum":
         acc = (counts.astype(np.uint64) * np.uint64(vmin % (1 << 64)))[:, None]
         acc = np.broadcast_to(acc, (n_segments, c)).copy()
@@ -783,6 +812,10 @@ class JaxBackend(ReduceBackend):
     way CPU tests check the kernel.  Interpret mode on a TPU is refused.
     Construction raises :class:`BackendUnavailable` when jax is missing
     or x64 cannot be enabled.
+
+    Each call an op makes to the device, from its NumPy inputs to its
+    NumPy output, runs inside a ``device_roundtrip`` span: the puts, the
+    dispatch, the device's work, the wait for it and the read back.
     """
 
     name = "jax"
@@ -803,6 +836,7 @@ class JaxBackend(ReduceBackend):
         self.use_pallas = self.platform == "tpu" or self.interpret
 
     # -- exact int64 matmul -------------------------------------------------
+    @_op_span
     def matmul(self, w: np.ndarray, grid: np.ndarray) -> np.ndarray:
         w = np.ascontiguousarray(w, np.int64)
         grid = np.ascontiguousarray(grid, np.int64)
@@ -827,7 +861,7 @@ class JaxBackend(ReduceBackend):
             "max": jax.ops.segment_max,
             "min": jax.ops.segment_min,
         }
-        with self._enable_x64():
+        with self._enable_x64(), span("device_roundtrip"):
             out = fns[op](
                 vals,
                 seg,
@@ -836,6 +870,7 @@ class JaxBackend(ReduceBackend):
             )
             return np.asarray(out)
 
+    @_op_span
     def block_reduce(self, grid, starts, ends, ufunc: np.ufunc) -> np.ndarray:
         op = _SEG_OPS.get(ufunc)
         if op is None or getattr(grid, "ndim", 0) != 2:
@@ -852,6 +887,7 @@ class JaxBackend(ReduceBackend):
         out = self._segment_apply(grid[idx], seg, nseg, op)
         return out.astype(grid.dtype, copy=False)
 
+    @_op_span
     def segment_reduce(self, col, order, starts, ufunc: np.ufunc = np.add):
         if not len(starts):
             return np.zeros(0, col.dtype)
@@ -864,18 +900,21 @@ class JaxBackend(ReduceBackend):
         return out.astype(col.dtype, copy=False)
 
     # -- factorize / dedup ----------------------------------------------------
+    @_op_span
     def factorize(self, col: np.ndarray) -> tuple:
         col = np.asarray(col)
-        with self._enable_x64():
+        with self._enable_x64(), span("device_roundtrip"):
             uniq, inv = self._jnp.unique(col, return_inverse=True)
-        uniq = np.asarray(uniq)
-        inv = np.asarray(inv).reshape(-1).astype(np.int64)
+            uniq = np.asarray(uniq)
+            inv = np.asarray(inv)
+        inv = inv.reshape(-1).astype(np.int64)
         # first-occurrence indices derived from the inverse (np.unique's
         # return_index contract), independent of jnp.unique tie-breaking
         first = np.full(len(uniq), len(inv), np.int64)
         np.minimum.at(first, inv, np.arange(len(inv), dtype=np.int64))
         return uniq, first, inv
 
+    @_op_span
     def pair_counts(self, group_ids, rows, peers, n_groups, rank_extent):
         m = len(rows)
         if m == 0 or rank_extent == 0 or n_groups == 0:
@@ -889,11 +928,12 @@ class JaxBackend(ReduceBackend):
             )
         stride = np.int64(int(peers.max()) + 1)
         codes = (group_ids * rank_extent + rows) * stride + peers
-        with self._enable_x64():
+        with self._enable_x64(), span("device_roundtrip"):
             uniq = np.asarray(self._jnp.unique(codes))
         counts = np.bincount(uniq // stride, minlength=n_groups * rank_extent)
         return counts.reshape(n_groups, rank_extent).astype(np.int64, copy=False)
 
+    @_op_span
     def pair_codes(self, group_ids, rows, peers, n_groups) -> tuple:
         m = len(rows)
         if m == 0 or n_groups == 0:
@@ -910,7 +950,7 @@ class JaxBackend(ReduceBackend):
                 group_ids, rows, peers, n_groups, strategy=("hybrid", 0)
             )
         comp = (group_ids * rank_extent + rows) * stride + peers
-        with self._enable_x64():
+        with self._enable_x64(), span("device_roundtrip"):
             uniq = np.asarray(self._jnp.unique(comp))
         return _decode_pair_codes(uniq, n_groups, rank_extent, stride)
 

@@ -77,6 +77,7 @@ from repro.core.backend import (  # noqa: F401  (re-exported kernel API)
     segment_spans,
 )
 from repro.core.regions import RegionRecorder, TraceBuffer, recording
+from repro.core.tracing import span
 
 
 @dataclass
@@ -237,14 +238,15 @@ class CommPatternProfiler:
         implementation (see :func:`repro.core.backend.resolve_backend`);
         ``impl="reference"`` is pure-Python and ignores it.
         """
-        if impl == "numpy":
-            return CommPatternProfiler._from_recorder_numpy(
-                rec, name=name, replication=replication, meta=meta, backend=backend
-            )
-        elif impl == "reference":
-            return CommPatternProfiler._from_recorder_reference(
-                rec, name=name, replication=replication, meta=meta
-            )
+        with span("reduce"):
+            if impl == "numpy":
+                return CommPatternProfiler._from_recorder_numpy(
+                    rec, name=name, replication=replication, meta=meta, backend=backend
+                )
+            if impl == "reference":
+                return CommPatternProfiler._from_recorder_reference(
+                    rec, name=name, replication=replication, meta=meta
+                )
         raise ValueError(f"unknown profiler impl: {impl!r}")
 
     @staticmethod
@@ -705,7 +707,7 @@ def profile_traced(
     first and may supply the profile (live/incremental harnesses); a
     ``None`` return falls through to the batch reduction.
     """
-    with recording() as rec:
+    with recording() as rec, span("eval_shape"):
         jax.eval_shape(fn, *args, **kwargs)
     cb = getattr(_observer_tls, "cb", None)
     if cb is not None:

@@ -197,6 +197,7 @@ import shutil
 import sys
 import tempfile
 import threading
+import time
 import weakref
 from dataclasses import dataclass
 from typing import Iterator, Mapping, Optional
@@ -205,6 +206,7 @@ import jax
 import numpy as np
 
 from repro.core.faultinject import maybe_fault
+from repro.core.tracing import count, span
 
 #: Environment knob: row columns of a :class:`TraceBuffer` spill to
 #: file-backed (np.memmap) storage once their combined in-RAM footprint
@@ -567,6 +569,12 @@ def _as_member_array(groups) -> np.ndarray:
     return np.ascontiguousarray(np.asarray(groups, np.int64).reshape(-1))
 
 
+def _count_miss(t0: int) -> None:
+    """Add the time since ``t0`` (ns) of a struct interned anew to the open
+    span's ``intern_ns``: misses can be many a trace, so they are no spans."""
+    count("intern_ns", time.perf_counter_ns() - t0)
+
+
 def _cat(parts: list, dtype) -> np.ndarray:
     if not parts:
         return np.zeros(0, dtype)
@@ -756,7 +764,8 @@ class StructTable:
         if hit is not None and hit[0] == self._version:
             return hit[1]
         if self._lazy:
-            view = self._materialize()
+            with span("materialize"):
+                view = self._materialize()
         else:
             view = StructView(
                 rank_lens=self._rank_len.view(),
@@ -933,12 +942,14 @@ class StructTable:
             mkey = None
         sid = self._fp.get(key)
         if sid is None:
+            t0 = time.perf_counter_ns()
             pairs = _as_pair_array(pairs)
             if self._lazy:
                 sid = self._append_lazy(n=n, kind=_KIND_P2P, payload=pairs)
             else:
                 sid = self.insert_p2p(pairs, n)
             self._fp[key] = sid
+            _count_miss(t0)
         if mkey is not None:
             self._id_memo[mkey] = (sid, pairs)
         return sid
@@ -964,12 +975,14 @@ class StructTable:
             mkey = None
         sid = self._fp.get(key)
         if sid is None:
+            t0 = time.perf_counter_ns()
             members = _as_member_array(members)
             if self._lazy:
                 sid = self._append_lazy(n=n, kind=_KIND_COLL, payload=members)
             else:
                 sid = self.insert_collective(members, n)
             self._fp[key] = sid
+            _count_miss(t0)
         if mkey is not None:
             self._id_memo[mkey] = (sid, members)
         return sid
@@ -1332,6 +1345,7 @@ class TraceBuffer:
         struct_id: int,
         nbytes: int,
     ) -> None:
+        count("rows")
         rid = self._regions.intern(region)
         pid = self._paths.intern(tuple(region_path))
         kid = self._kinds.intern(kind)

@@ -90,6 +90,30 @@ def test_limb_dot_compiles_for_v5e(topo, one_chip):
     assert mem.temp_size_in_bytes < _hbm(topo) / 4
 
 
+@pytest.mark.parametrize("kernel", ["sum", "max", "min", "limb_dot"])
+def test_device_code_keeps_its_stable_name_for_v5e(topo, one_chip, kernel):
+    """The Pallas kernels and the limb dot keep the names a device trace
+    groups their time by: ``repro_segment_<op>`` on the kernel's custom
+    call, ``repro.limb_dot`` in the dot's op metadata."""
+    i32 = jnp.int32
+    if kernel == "limb_dot":
+        fn = B._limb_dot_fn()
+        args = [
+            _sds((8, 256), jnp.int8, one_chip),
+            _sds((256, 128), jnp.int8, one_chip),
+        ]
+        want = "repro.limb_dot"
+    else:
+        n_rows = 2 * B._SEG_ROWS
+        fn = B._seg_kernel(kernel, 1, n_rows, 1, 128, False)
+        vals = (1, n_rows, 128) if kernel == "sum" else (n_rows, 128)
+        args = [_sds((2,), i32, one_chip) for _ in range(4)]
+        args += [_sds((n_rows,), i32, one_chip), _sds(vals, i32, one_chip)]
+        want = f"repro_segment_{kernel}"
+    text = fn.lower(*args).compile().as_text()
+    assert want in text
+
+
 def _app_bytes(compiled) -> int:
     mem = compiled.memory_analysis()
     return (
