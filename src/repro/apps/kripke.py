@@ -76,20 +76,56 @@ def _octant_signs(octant: int) -> tuple:
     return (1 if octant & 1 else -1, 1 if octant & 2 else -1, 1 if octant & 4 else -1)
 
 
+@lru_cache(maxsize=None)
+def _plane_step(axis: int, a: float):
+    """Scan body of the in-place recurrence along ``axis``: overwrite plane
+    ``i`` of the carried array with ``a * prev + b_i`` and carry that plane
+    on.  One function per (axis, a), so JAX reuses its traced jaxpr wherever
+    the shapes repeat."""
+
+    def step(carry, i):
+        psi, prev = carry
+        start = _plane_start(psi.ndim, axis, i)
+        plane = a * prev + lax.dynamic_slice(psi, start, prev.shape)
+        return (lax.dynamic_update_slice(psi, plane, start), plane), None
+
+    return step
+
+
+def _plane_start(ndim: int, axis: int, i):
+    # unsigned indices are not normalised for negative values: no extra ops
+    zero = np.uint32(0)
+    return (zero,) * axis + (i,) + (zero,) * (ndim - axis - 1)
+
+
 def _axis_recurrence(src, inflow, axis: int, w: float, sig: float, sign: int):
     """psi_i = a * psi_{i-1} + b_i with a = w/(sig+w), b = src/(sig+w);
     descending directions sweep the axis in reverse.  ``inflow`` (the
     upwind face, size 1 along ``axis``) is psi_{-1}.  ``axis`` is 2, 3 or 4
     (x, y, z); the pass runs under the named scope ``kripke.scan_<x|y|z>``.
 
-    A sequential scan along the axis: a block seeded with its upwind
+    A sequential loop along the axis: a block seeded with its upwind
     neighbour's face repeats the single-domain arithmetic exactly.  (A
     reversed ``lax.associative_scan`` computed wrong values on TPU v5e at
     the Tioga global problem, 6x6x32x64x64x4x4, with jax 0.9.0.)
+
+    x and y overwrite each plane of ``b`` where it lies.  On TPU, XLA puts
+    the last spatial axis (z, ahead of the small direction and group dims)
+    in the tile's lanes, so a plane along it would be one lane of every
+    tile; z keeps a scan that stacks its planes in a buffer of their own.
     """
     with jax.named_scope(f"kripke.scan_{AXIS_NAMES[axis - 2]}"):
         a = w / (sig + w)
-        b = jnp.moveaxis(src / (sig + w), axis, 0)
+        b = src / (sig + w)
+        if axis < src.ndim - 3:
+            planes = np.arange(src.shape[axis], dtype=np.uint32)[::sign]
+            start = _plane_start(src.ndim, axis, planes[0])
+            # the upwind plane outside the loop, so the carry has its type
+            first = a * inflow + lax.dynamic_slice(b, start, inflow.shape)
+            psi = lax.dynamic_update_slice(b, first, start)
+            (psi, _), _ = lax.scan(_plane_step(axis, a), (psi, first), planes[1:])
+            return psi
+        b = jnp.moveaxis(b, axis, 0)
         inflow = jnp.moveaxis(inflow, axis, 0)[0]
 
         def step(prev, b_i):

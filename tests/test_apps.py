@@ -5,14 +5,17 @@ Beatnik-style global-communication mini-app that stresses the trace
 substrate's worst case (all-rank far-field coupling, per-step structure
 mutation)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax import lax
 
 from helpers import run_with_devices
 
 from repro.apps.amg import AMGConfig, make_rhs, profile as amg_profile, solve
 from repro.apps.beatnik import BeatnikConfig, _migration, profile as beatnik_profile
-from repro.apps.kripke import KripkeConfig, profile as kripke_profile
+from repro.apps.kripke import KripkeConfig, _axis_recurrence, profile as kripke_profile
 from repro.apps.laghos import (
     LaghosConfig, make_state, profile as laghos_profile, run_steps
 )
@@ -83,6 +86,59 @@ def test_kripke_distributed_matches_reference_8ranks():
                                    rtol=2e-5, atol=2e-5)
         print("OK")
     """)
+
+
+def _stacked_scan_recurrence(src, inflow, axis, w, sig, sign):
+    """The recurrence as a scan that stacks its planes: the axis moved to
+    the front, ``lax.scan``, the upwind plane concatenated, the axis moved
+    back (the form every axis used before x and y ran in place)."""
+    a = w / (sig + w)
+    b = jnp.moveaxis(src / (sig + w), axis, 0)
+    inflow = jnp.moveaxis(inflow, axis, 0)[0]
+
+    def step(prev, b_i):
+        psi = a * prev + b_i
+        return psi, psi
+
+    # the upwind zone seeds the carry, so it has the type of the rows
+    if sign > 0:
+        first = a * inflow + b[0]
+        _, rest = lax.scan(step, first, b[1:])
+        psi = jnp.concatenate([first[None], rest])
+    else:
+        first = a * inflow + b[-1]
+        _, rest = lax.scan(step, first, b[:-1], reverse=True)
+        psi = jnp.concatenate([rest, first[None]])
+    return jnp.moveaxis(psi, 0, axis)
+
+
+@pytest.mark.parametrize("sign", [1, -1])
+@pytest.mark.parametrize("axis", [2, 3, 4])
+def test_kripke_axis_recurrence_matches_stacked_scan_and_float64(axis, sign):
+    """Every axis and direction gives bitwise the stacked scan's result
+    under jit, and the float64 loop's to 1e-6, from a nonzero inflow on
+    planes that are not whole tiles."""
+    shape = (2, 3, 5, 6, 7, 4, 4)
+    w, sig = 0.35, 1.0
+    rng = np.random.default_rng(axis * 10 + sign)
+    src = rng.uniform(0.5, 1.5, shape).astype(np.float32)
+    face = list(shape)
+    face[axis] = 1
+    inflow = rng.uniform(0.5, 1.5, face).astype(np.float32)
+
+    args = (src, inflow, axis, w, sig, sign)
+    static = dict(static_argnums=(2, 3, 4, 5))
+    got = np.asarray(jax.jit(_axis_recurrence, **static)(*args))
+    want = np.asarray(jax.jit(_stacked_scan_recurrence, **static)(*args))
+    np.testing.assert_array_equal(got, want)
+
+    a = w / (sig + w)
+    b = np.moveaxis(src.astype(np.float64) / (sig + w), axis, 0)
+    prev = np.moveaxis(inflow.astype(np.float64), axis, 0)[0]
+    ref = np.empty_like(b)
+    for i in range(len(b)) if sign > 0 else reversed(range(len(b))):
+        prev = ref[i] = a * prev + b[i]
+    np.testing.assert_allclose(got, np.moveaxis(ref, 0, axis), rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ the same tests and only the worker given this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,28 +124,93 @@ def _app_bytes(compiled) -> int:
     )
 
 
-def test_kripke_tioga_sweep_compiles_on_one_chip(topo):
-    """The Tioga (2,2,2) global problem on one chip — 32x64x64 zones, 6x6
-    sets, 4x4 dirs/groups, 2 unfused octants — fits in its HBM with the
-    regions in the program."""
+@pytest.fixture(scope="module")
+def tioga_sweep(topo):
+    """The Tioga (2,2,2) global problem — 32x64x64 zones, 6x6 sets, 4x4
+    dirs/groups, 2 unfused octants — compiled once per mesh."""
     from repro.apps import kripke
     from repro.apps.stencil import Decomp3D
 
-    mesh = compat.make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
-    cfg = kripke.KripkeConfig(
-        decomp=Decomp3D(1, 1, 1),
-        nx=32,
-        ny=64,
-        nz=64,
-        n_octants=2,
-        fuse_messages=False,
-    )
-    q = _sds((6, 6, 32, 64, 64, 4, 4), jnp.float32, NamedSharding(mesh, P()))
-    compiled = jax.jit(kripke.distributed_sweep(cfg, mesh)).lower(q).compile()
+    compiled = {}
+
+    def get(decomp):
+        if decomp not in compiled:
+            n = int(np.prod(decomp))
+            mesh = compat.make_mesh(decomp, ("x", "y", "z"), devices=topo.devices[:n])
+            zones = (32, 64, 64)
+            nx, ny, nz = (z // d for z, d in zip(zones, decomp))
+            cfg = kripke.KripkeConfig(
+                decomp=Decomp3D(*decomp),
+                nx=nx,
+                ny=ny,
+                nz=nz,
+                n_octants=2,
+                fuse_messages=False,
+            )
+            spec = P(None, None, "x", "y", "z", None, None)
+            q = _sds((6, 6, *zones, 4, 4), jnp.float32, NamedSharding(mesh, spec))
+            sweep = jax.jit(kripke.distributed_sweep(cfg, mesh))
+            compiled[decomp] = sweep.lower(q).compile()
+        return compiled[decomp]
+
+    return get
+
+
+def test_kripke_tioga_sweep_compiles_on_one_chip(tioga_sweep, topo):
+    """The Tioga global problem fits one chip's HBM with the regions in the
+    program."""
+    compiled = tioga_sweep((1, 1, 1))
     assert "commr::main" in compiled.as_text()
     q_bytes = 4 * 6 * 6 * 32 * 64 * 64 * 4 * 4
     assert compiled.memory_analysis().argument_size_in_bytes >= q_bytes
     assert _app_bytes(compiled) < _hbm(topo)
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\](?:\{([\d,]*))?")
+
+
+def _swept_plane_writes(text: str, scopes) -> list:
+    """``(op_name, swept dims, minor-to-major layout)`` of each
+    dynamic-update-slice under one of ``scopes``: the swept dims are those
+    along which the update is smaller than the array it is written into."""
+    lines = text.splitlines()
+    dims = {}
+    for m in filter(None, map(_HLO_DEF.match, lines)):
+        dims[m[1]] = [int(d) for d in m[2].split(",") if d]
+    found = []
+    for line in lines:
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if " dynamic-update-slice(" not in line or not op_name:
+            continue
+        if not any(s in op_name[1] for s in scopes):
+            continue
+        args = re.search(r"dynamic-update-slice\(%([^,\s]+), %([^,\s]+)", line)
+        operand, update = dims[args[1]], dims[args[2]]
+        swept = [i for i, (o, u) in enumerate(zip(operand, update)) if u < o]
+        layout = [int(d) for d in _HLO_DEF.match(line)[3].split(",")]
+        found.append((op_name[1], swept, layout))
+    return found
+
+
+@pytest.mark.parametrize(
+    "decomp,parent_temp", [((1, 1, 1), 2_417_725_440), ((2, 2, 1), 1_502_599_680)]
+)
+def test_kripke_x_and_y_sweeps_write_whole_tiles(tioga_sweep, decomp, parent_temp):
+    """The x and y recurrences write each plane in place: no plane write
+    under ``kripke.scan_x``/``_y`` has its swept dim among the two minor-most
+    of its layout, where one plane would be one row of every (8,128) tile.
+    Temp memory is no more than the stacked-scan form needed (the bytes
+    given), and the three scan scopes are still in the optimized HLO."""
+    compiled = tioga_sweep(decomp)
+    text = compiled.as_text()
+    writes = _swept_plane_writes(text, ("kripke.scan_x", "kripke.scan_y"))
+    assert any("kripke.scan_x" in w[0] for w in writes)
+    assert any("kripke.scan_y" in w[0] for w in writes)
+    for op_name, swept, layout in writes:
+        assert not set(swept) & set(layout[:2]), (op_name, swept, layout)
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
+    for axis in "xyz":
+        assert f"kripke.scan_{axis}" in text
 
 
 @pytest.mark.parametrize("decomp", [(1, 1, 1), (2, 2, 1)])
