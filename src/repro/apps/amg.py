@@ -1,26 +1,42 @@
-"""AMG2023 analog — multigrid solver with per-level communication regions.
+"""AMG2023 analog — conjugate gradients preconditioned by a multigrid V-cycle,
+with per-level communication regions.
 
-AMG2023 (paper §III-A) is an algebraic multigrid solver on top of hypre; its
-communication is a hierarchy of halo exchanges whose character changes with
-the multigrid level: fine levels move the most data between few neighbors,
-coarse levels involve many ranks with little data (paper Figs. 2-3 — over
-100 source ranks at MG level 6+ on 512 processes).
+AMG2023 (paper §III-A) solves a Poisson problem with hypre's conjugate
+gradients preconditioned by BoomerAMG V-cycles; its communication is a
+hierarchy of halo exchanges whose character changes with the multigrid
+level — fine levels move the most data between few neighbors, coarse levels
+involve many ranks with little data (paper Figs. 2-3, over 100 source ranks
+at MG level 6+ on 512 processes) — plus the Krylov method's global inner
+products.
 
-We build the geometric analog: a 3-D 7-point Poisson V-cycle over the same
-block decomposition the paper uses.  Distributed levels coarsen by 2 while
-the per-rank block stays ≥ ``min_local``; below that the problem is gathered
-to every rank (``coarse_solve`` region — the all-ranks participation the
-paper observes at coarse levels) and solved redundantly.
+We build the geometric analog on the same block decomposition the paper
+uses.  The problem is ``A u = f`` with ``A = -Δ`` (7-point, h = 1, zero
+Dirichlet boundary).  Level k has spacing ``h_k = 2^k`` and operator
+``A_k = -Δ / h_k²``; distributed levels coarsen by 2 (restriction: the mean
+of each 2x2x2 block; prolongation: piecewise constant, so restriction is
+prolongation's transpose over 8) while every *global* dim stays
+≥ ``min_global``; below that the residual is gathered to every rank and
+solved redundantly by ``n_coarse_iters`` weighted-Jacobi sweeps from zero
+(``coarse_solve`` — the all-ranks participation the paper observes at
+coarse levels).  One V-cycle from a zero guess, with as many pre- as
+post-smoothing weighted-Jacobi sweeps, is a fixed symmetric positive
+definite preconditioner; ``solve`` runs ``n_cycles`` iterations of
+preconditioned conjugate gradients, one V-cycle and one matvec each.
 
 Regions:
-  mg_level_<k>   smoother/prolongation halo exchanges on level k (Figs. 2-3)
-  MatVecComm     residual matvec halo (hypre's MatVecComm analog, paper §III-B)
-  coarse_solve   gather + redundant coarse solve
-  reduce_norm    residual-norm reduction
+  mg_level_<k>   smoother halo exchanges on level k (Figs. 2-3)
+  MatVecComm     matvec halos: the Krylov operator and each level's
+                 residual (hypre's MatVecComm analog, paper §III-B)
+  coarse_solve   the all-rank gather of the coarse residual
+  reduce_norm    the Krylov inner products and norms (psums)
+
+Device phases carry ``jax.named_scope``s: ``amg.smooth``, ``amg.residual``,
+``amg.restrict``, ``amg.prolong``, ``amg.coarse``, ``amg.matvec`` and
+``amg.krylov``.
 
 Weak scaling mirrors the paper: per-rank fine block fixed (default 32x32x16),
-global problem grows with ranks — note more ranks ⇒ a deeper gathered
-hierarchy, matching "runs on Dane had more levels".
+global problem grows with ranks — note more ranks ⇒ a deeper hierarchy,
+matching "runs on Dane had more levels".
 """
 
 from __future__ import annotations
@@ -45,11 +61,11 @@ class AMGConfig:
     ny: int = 32
     nz: int = 16
     n_pre: int = 2        # pre-smoothing sweeps
-    n_post: int = 2       # post-smoothing sweeps
+    n_post: int = 2       # post-smoothing sweeps (as many as pre: symmetric)
     n_coarse_iters: int = 8
     omega: float = 0.8    # weighted-Jacobi damping
     min_global: int = 8   # gather when a *global* dim would drop below this
-    n_cycles: int = 1
+    n_cycles: int = 1     # PCG iterations, one V-cycle each
     dtype: str = "float32"
 
     @property
@@ -74,24 +90,24 @@ class AMGConfig:
         return lvl
 
 
-def _jacobi(u, f, cfg: AMGConfig, level: int, region: str):
-    """One weighted-Jacobi sweep: u += ω/6 (f - A u), A = -Δ (7-point)."""
+def _matvec(u, cfg: AMGConfig, level: int, region: str):
+    """``A_k u = -Δu / h_k²`` with the halo exchanged in ``region``."""
     with comm_region(region):
         ghosts = halo_exchange(u, cfg.decomp)
-    up = pad_with_halo(u, ghosts)
-    au = -laplacian_7pt(up)           # A = -Δ, h = 1 at every level
-    return u + (cfg.omega / 6.0) * (f - au)
+    return -laplacian_7pt(pad_with_halo(u, ghosts), h2=4.0**level)
 
 
-def _residual(u, f, cfg: AMGConfig):
-    with comm_region("MatVecComm"):
-        ghosts = halo_exchange(u, cfg.decomp)
-    up = pad_with_halo(u, ghosts)
-    return f + laplacian_7pt(up)
+def _jacobi(u, f, cfg: AMGConfig, level: int):
+    """One weighted-Jacobi sweep on level k: ``u += ω D_k⁻¹ (f - A_k u)``,
+    ``D_k = 6 / h_k²``."""
+    with jax.named_scope("amg.smooth"):
+        au = _matvec(u, cfg, level, f"mg_level_{level}")
+        return u + (cfg.omega * 4.0**level / 6.0) * (f - au)
 
 
 def _restrict(r):
-    """Full-weighting 2x coarsening (local: blocks stay rank-aligned)."""
+    """2x coarsening by the mean of each 2x2x2 block (local: blocks stay
+    rank-aligned) — prolongation's transpose over 8."""
     s = r.shape
     r = r.reshape(s[0] // 2, 2, s[1] // 2, 2, s[2] // 2, 2)
     return r.mean(axis=(1, 3, 5))
@@ -112,7 +128,7 @@ def _gather_global(x, cfg: AMGConfig):
     return g.reshape(dc.px * lx, dc.py * ly, dc.pz * lz)
 
 
-def _my_block(g, cfg: AMGConfig, local_shape):
+def _my_block(g, local_shape):
     ix = lax.axis_index("x")
     iy = lax.axis_index("y")
     iz = lax.axis_index("z")
@@ -120,57 +136,90 @@ def _my_block(g, cfg: AMGConfig, local_shape):
     return lax.dynamic_slice(g, (ix * lx, iy * ly, iz * lz), (lx, ly, lz))
 
 
-def _coarse_solve(f, cfg: AMGConfig):
-    """Gather the coarse problem to every rank; solve redundantly.
+def _coarse_solve(r, cfg: AMGConfig, level: int):
+    """Gather the coarse residual to every rank and solve ``A_k e = r``
+    redundantly by ``n_coarse_iters`` weighted-Jacobi sweeps from zero: a
+    fixed polynomial in ``A_k``, so linear and symmetric.
 
     This is the all-ranks-involved pattern the paper measures at coarse MG
     levels (src ranks ≈ everyone, little data).
     """
     with comm_region("coarse_solve"):
-        fg = _gather_global(f, cfg)
-    u = jnp.zeros_like(fg)
-    for _ in range(cfg.n_coarse_iters):
-        up = jnp.pad(u, 1)
-        au = -laplacian_7pt(up)
-        u = u + (cfg.omega / 6.0) * (fg - au)
-    return _my_block(u, cfg, f.shape)
+        rg = _gather_global(r, cfg)
+    with jax.named_scope("amg.coarse"):
+        h2 = 4.0**level
+        e = jnp.zeros_like(rg)
+        for _ in range(cfg.n_coarse_iters):
+            ae = -laplacian_7pt(jnp.pad(e, 1), h2=h2)
+            e = e + (cfg.omega * h2 / 6.0) * (rg - ae)
+        return _my_block(e, r.shape)
 
 
-def v_cycle(u, f, cfg: AMGConfig, level: int = 0):
-    region = f"mg_level_{level}"
-    global_min = min(s * p for s, p in zip(u.shape, cfg.decomp.shape))
+def v_cycle(r, cfg: AMGConfig, level: int = 0):
+    """One V-cycle from a zero guess on ``A_k e = r``: the preconditioner."""
+    global_min = min(s * p for s, p in zip(r.shape, cfg.decomp.shape))
     if global_min // 2 < cfg.min_global:
-        return _coarse_level(u, f, cfg)
+        return _coarse_solve(r, cfg, level)
+    e = jnp.zeros_like(r)
     for _ in range(cfg.n_pre):
-        u = _jacobi(u, f, cfg, level, region)
-    r = _residual(u, f, cfg)
-    f_c = _restrict(r)
-    e_c = v_cycle(jnp.zeros_like(f_c), f_c, cfg, level + 1)
-    u = u + _prolong(e_c)
+        e = _jacobi(e, r, cfg, level)
+    with jax.named_scope("amg.residual"):
+        res = r - _matvec(e, cfg, level, "MatVecComm")
+    with jax.named_scope("amg.restrict"):
+        r_c = _restrict(res)
+    e_c = v_cycle(r_c, cfg, level + 1)
+    with jax.named_scope("amg.prolong"):
+        e = e + _prolong(e_c)
     for _ in range(cfg.n_post):
-        u = _jacobi(u, f, cfg, level, region)
-    return u
+        e = _jacobi(e, r, cfg, level)
+    return e
 
 
-def _coarse_level(u, f, cfg: AMGConfig):
-    r = _residual(u, f, cfg)
-    return u + _coarse_solve(r, cfg)
+def _dot(a, b):
+    """Global inner product: a local sum and one psum over every rank."""
+    with comm_region("reduce_norm"):
+        return coll.psum(jnp.vdot(a, b), AXIS_NAMES)
+
+
+def pcg(f, cfg: AMGConfig):
+    """``n_cycles`` iterations of V-cycle-preconditioned conjugate gradients
+    on ``A u = f`` from ``u = 0``, inside ``shard_map``.  Returns ``u`` and
+    the relative residual norm ``‖r‖/‖f‖`` of the *recursive* residual (the
+    one the iteration updates, which saves a final matvec and its halo).
+
+    The iterations are unrolled, as the levels are, so a trace counts each.
+    """
+    with jax.named_scope("amg.krylov"):
+        ff = _dot(f, f)
+        u, r = jnp.zeros_like(f), f
+    z = v_cycle(r, cfg)
+    with jax.named_scope("amg.krylov"):
+        p, rz = z, _dot(r, z)
+    for k in range(cfg.n_cycles):
+        with jax.named_scope("amg.matvec"):
+            q = _matvec(p, cfg, 0, "MatVecComm")
+        with jax.named_scope("amg.krylov"):
+            alpha = rz / _dot(p, q)
+            u = u + alpha * p
+            r = r - alpha * q
+        if k + 1 < cfg.n_cycles:
+            z = v_cycle(r, cfg)
+            with jax.named_scope("amg.krylov"):
+                rz, rz_old = _dot(r, z), rz
+                p = z + (rz / rz_old) * p
+    with jax.named_scope("amg.krylov"):
+        return u, jnp.sqrt(_dot(r, r) / ff)
 
 
 def solve(cfg: AMGConfig, mesh):
-    """jit-able: run n_cycles V-cycles + residual norm.  Global arrays."""
+    """jit-able PCG–AMG solve of ``A u = f`` on global arrays: returns the
+    solution and its relative (recursive) residual norm."""
     spec = P(*AXIS_NAMES)
 
     def run(f):
         def inner(f):
             with comm_region("main"):
-                u = jnp.zeros_like(f)
-                for _ in range(cfg.n_cycles):
-                    u = v_cycle(u, f, cfg, 0)
-                r = _residual(u, f, cfg)
-                with comm_region("reduce_norm"):
-                    rn = jnp.sqrt(coll.psum((r * r).sum(), AXIS_NAMES))
-                return u, rn
+                return pcg(f, cfg)
         return compat.shard_map(inner, mesh=mesh, in_specs=spec,
                                 out_specs=(spec, P()))(f)
     return run

@@ -165,14 +165,20 @@ def test_amg_coarse_level_involves_everyone():
 
 
 def test_amg_vcycle_converges():
-    cfg = AMGConfig(decomp=Decomp3D(1, 1, 1), nx=16, ny=16, nz=16, n_cycles=1)
-    mesh = cfg.decomp.make_mesh()
+    """The V-cycle-preconditioned CG solve of the smooth RHS on a 32x32x16
+    grid reaches a relative residual of 1e-5 in 10 iterations, as the solve
+    reports it and recomputed in float64 from ``u`` (float32 rounding of
+    ``u`` floors the latter near 3e-6 here).  The stationary V-cycle it
+    replaced reads 0.067 after 10 cycles."""
+    cfg = AMGConfig(decomp=Decomp3D(1, 1, 1), nx=32, ny=32, nz=16, n_cycles=10)
     f = make_rhs(cfg)
-    run = solve(cfg, mesh)
-    _, r1 = run(f)
-    cfg4 = AMGConfig(decomp=Decomp3D(1, 1, 1), nx=16, ny=16, nz=16, n_cycles=4)
-    _, r4 = solve(cfg4, mesh)(f)
-    assert float(r4) < float(r1) < float(jnp.sqrt((f * f).sum()))
+    u, relres = jax.jit(solve(cfg, cfg.decomp.make_mesh()))(f)
+    u, f = np.asarray(u, np.float64), np.asarray(f, np.float64)
+    p = np.pad(u, 1)
+    au = 6 * u - (p[:-2, 1:-1, 1:-1] + p[2:, 1:-1, 1:-1] + p[1:-1, :-2, 1:-1]
+                  + p[1:-1, 2:, 1:-1] + p[1:-1, 1:-1, :-2] + p[1:-1, 1:-1, 2:])
+    true = np.linalg.norm(f - au) / np.linalg.norm(f)
+    assert float(relres) <= 1e-5 and true <= 1e-5
 
 
 def test_amg_distributed_matches_reference_8ranks():
@@ -180,12 +186,12 @@ def test_amg_distributed_matches_reference_8ranks():
         import numpy as np, jax, jax.numpy as jnp
         from repro.apps.amg import AMGConfig, make_rhs, solve, reference_solve
         from repro.apps.stencil import Decomp3D
-        cfg = AMGConfig(decomp=Decomp3D(2, 2, 2), nx=8, ny=8, nz=8)
+        cfg = AMGConfig(decomp=Decomp3D(2, 2, 2), nx=8, ny=8, nz=8, n_cycles=4)
         mesh = cfg.decomp.make_mesh()
         f = make_rhs(cfg)
-        u, rn = solve(cfg, mesh)(f)
+        u, rn = jax.jit(solve(cfg, mesh))(f)
         ref_run, ref_cfg = reference_solve(cfg)
-        u_ref, rn_ref = ref_run(f)
+        u_ref, rn_ref = jax.jit(ref_run)(f)
         np.testing.assert_allclose(np.asarray(u), np.asarray(u_ref),
                                    rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(float(rn), float(rn_ref), rtol=1e-4)

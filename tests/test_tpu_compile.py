@@ -230,3 +230,50 @@ def test_laghos_step_compiles(topo, decomp):
     if n > 1:
         assert "collective-permute" in text
     assert _app_bytes(compiled) < _hbm(topo) / 100
+
+
+#: Temp bytes of the amg-table3 exec step for a v5e (compile): the whole
+#: 22-iteration solve at 256x256x128 float32 on one chip.
+AMG_DANE_TEMP = 3_044_500_992
+
+
+def test_amg_dane_solve_compiles_on_one_chip(topo):
+    """The amg-table3 exec step — the Dane (8,8,8) problem as one
+    256x256x128 float32 grid, the configuration's whole PCG–AMG solve —
+    compiles on mesh (1,1,1) with its ``commr::`` region scopes and the
+    ``amg.*`` device scopes, in the temp bytes recorded above."""
+    import json
+
+    from repro.apps import amg
+    from repro.apps.stencil import Decomp3D
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs", "amg-table3.json")) as f:
+        cfg = json.load(f)
+    shape = [z * p for z, p in zip(cfg["zones_per_rank"], cfg["exec_point"])]
+    mesh = compat.make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
+    acfg = amg.AMGConfig(
+        decomp=Decomp3D(1, 1, 1),
+        nx=shape[0],
+        ny=shape[1],
+        nz=shape[2],
+        n_pre=cfg["n_pre"],
+        n_post=cfg["n_post"],
+        n_coarse_iters=cfg["n_coarse_iters"],
+        omega=cfg["omega"],
+        min_global=cfg["min_global"],
+        n_cycles=cfg["n_cycles"],
+    )
+    f = _sds(tuple(shape), jnp.float32, NamedSharding(mesh, P("x", "y", "z")))
+    compiled = jax.jit(amg.solve(acfg, mesh)).lower(f).compile()
+    text = compiled.as_text()
+    levels = acfg.n_dist_levels()
+    assert levels == 4
+    regions = {"main", "reduce_norm", "MatVecComm"}
+    regions |= {f"mg_level_{k}" for k in range(levels)}
+    assert regions <= set(re.findall(r"commr::(\w+)", text))
+    phases = {"smooth", "residual", "restrict", "prolong", "coarse", "matvec", "krylov"}
+    assert phases <= set(re.findall(r"amg\.(\w+)/", text))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp <= AMG_DANE_TEMP
+    assert _app_bytes(compiled) < _hbm(topo) / 4
