@@ -13,7 +13,9 @@ from jax import lax
 
 from helpers import run_with_devices
 
-from repro.apps.amg import AMGConfig, make_rhs, profile as amg_profile, solve
+from repro.apps.amg import (
+    AMGConfig, _prolong, _restrict, make_rhs, profile as amg_profile, solve
+)
 from repro.apps.beatnik import BeatnikConfig, _migration, profile as beatnik_profile
 from repro.apps.kripke import KripkeConfig, _axis_recurrence, profile as kripke_profile
 from repro.apps.laghos import (
@@ -179,6 +181,36 @@ def test_amg_vcycle_converges():
                   + p[1:-1, 2:, 1:-1] + p[1:-1, 1:-1, :-2] + p[1:-1, 1:-1, 2:])
     true = np.linalg.norm(f - au) / np.linalg.norm(f)
     assert float(relres) <= 1e-5 and true <= 1e-5
+
+
+#: Each level's shape of the Dane hierarchy scaled down, and one non-cubic.
+RESTRICT_SHAPES = [(32, 32, 16), (16, 16, 8), (8, 8, 4), (6, 20, 10)]
+
+
+@pytest.mark.parametrize("shape", RESTRICT_SHAPES)
+def test_amg_restrict_is_the_block_mean(shape):
+    """The restriction is the mean of each 2x2x2 block: the float64 NumPy
+    mean of the same float32 values, to float32 rounding."""
+    r = np.random.default_rng(sum(shape)).standard_normal(shape).astype(np.float32)
+    got = jax.jit(_restrict)(jnp.asarray(r))
+    assert got.dtype == jnp.float32
+    x, y, z = shape
+    ref = r.astype(np.float64).reshape(x // 2, 2, y // 2, 2, z // 2, 2)
+    np.testing.assert_allclose(np.asarray(got), ref.mean(axis=(1, 3, 5)),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape", RESTRICT_SHAPES)
+def test_amg_restrict_is_prolongation_transposed_over_8(shape):
+    """``<R r, e_c> · 8 == <r, P e_c>``: restriction and prolongation are
+    each other's transposes up to 8, which keeps the V-cycle symmetric."""
+    rng = np.random.default_rng(len(shape) + sum(shape))
+    r = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    e_c = jnp.asarray(rng.standard_normal([n // 2 for n in shape]), jnp.float32)
+    lhs = 8 * jnp.vdot(jax.jit(_restrict)(r), e_c)
+    rhs = jnp.vdot(r, jax.jit(_prolong)(e_c))
+    scale = 4 * np.sqrt(r.size)  # |<r, P e_c>| for unit normals is ~ sqrt(8 n)
+    assert abs(float(lhs) - float(rhs)) <= 1e-5 * scale
 
 
 def test_amg_distributed_matches_reference_8ranks():
